@@ -26,6 +26,7 @@ import argparse
 
 from repro.configs.registry import ARCHS, get_smoke_config
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.decode import layer_grouped_matmul_shapes, layer_matmul_shapes
 
 
@@ -81,6 +82,7 @@ def main(argv=None) -> None:
                     help="sweep real model dims (slow on CPU) instead of "
                          "smoke-scale configs")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     sweep(args.archs, args.batch_sizes, full=args.full,
           dtypes=tuple(args.dtypes) if args.dtypes else None, reps=args.reps)
 

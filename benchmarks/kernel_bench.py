@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core import encoding
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 
 #: serving batch / admission chunk defining the two MoE operating points
 MOE_ARCH = "phi3.5-moe-42b-a6.6b"
@@ -207,6 +208,7 @@ def main():
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--out", default="BENCH_kernels.json")
     args = ap.parse_args()
+    enable_compile_cache()
     results = collect(smoke=args.smoke, reps=args.reps)
     for g in results["grouped"]:
         s = g["shape"]

@@ -39,7 +39,9 @@ def _roofline_summary():
 
 def main() -> None:
     from benchmarks import kernel_bench, paper_tables
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     all_rows = {}
     print("name,us_per_call,derived")
     for name, fn in paper_tables.ALL.items():

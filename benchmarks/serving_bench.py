@@ -111,6 +111,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import analysis  # noqa: E402  (benchmarks/analysis.py, same directory)
 
 from repro.configs.registry import get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.decode import quantize_for_serving
 from repro.models.model import init_params
 from repro.serving.engine import DecodeEngine, Request, SamplerConfig
@@ -620,6 +621,7 @@ def main():
                     help="virtual-clock seconds per prefill chunk")
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if args.smoke:

@@ -14,6 +14,7 @@ import argparse
 import jax
 
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_mesh_for
 from repro.launch.train import train
 from repro.models.config import reduced
 from repro.models.decode import packed_bits_per_weight, quantize_for_serving
@@ -39,8 +40,7 @@ def main():
     print(f"[train] {cfg.name} reduced to {cfg.param_count()/1e6:.1f}M params; "
           f"QAT with STE ternary weights")
 
-    n = jax.device_count()
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh_for(jax.device_count())
     out = train(cfg, steps=args.steps, global_batch=args.global_batch,
                 seq_len=args.seq_len, mesh=mesh, ckpt_dir=args.ckpt_dir,
                 checkpoint_every=50, compress_grads=args.compress_grads,

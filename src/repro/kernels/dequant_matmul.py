@@ -8,9 +8,18 @@ then contracted on the MXU.  HBM traffic for weights drops 10× vs bf16 and
 attacks.
 
 Decode uses arithmetic base-3 digit extraction (5 div-mod-3 steps on the VPU)
-rather than a table gather: divides by the constant 3 lower to
-multiply-by-reciprocal, and the whole decode vectorizes across the 8×128 VREG
-lanes with no dynamic addressing.
+rather than a table gather, and never interleaves the digits back into
+weight order: byte ``j`` of a row holds the trits of weight columns
+``5j .. 5j+4``, so base-3 digit ``i`` of a ``[bo, bnb]`` byte tile is itself a
+``[bo, bnb]`` trit *plane* covering columns ``5j + i``.  The activations are
+permuted once outside the kernel into the matching digit-major layout
+(:func:`digit_planes`), and the tile's product is five lane-aligned MXU
+contractions, one per plane.  Every in-kernel value keeps the byte tile's
+``(sublane, lane)`` shape — no minor dim of 5, no shape cast — which is what
+lets Mosaic compile the kernel at real model widths.
+
+Float activations accumulate in f32; int8 activations accumulate exactly in
+int32 on the MXU's integer path (:mod:`repro.kernels.w2a8_matmul`).
 """
 
 from __future__ import annotations
@@ -22,35 +31,95 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.encoding import TRITS_PER_BYTE
+from repro.kernels.backend import resolve_interpret
 
 
-def _unpack_block(p: jax.Array, out_dtype) -> jax.Array:
-    """[bo, bn/5] uint8 → [bo, bn] trits in out_dtype (arithmetic decode)."""
+def digit_planes(x: jax.Array, nb: int) -> jax.Array:
+    """``[..., R, N]`` activations → ``[..., 5, R, nb]`` digit-major planes.
+
+    Plane ``i`` holds activation columns ``5j + i`` (``j < nb``), the columns
+    that meet base-3 digit ``i`` of packed byte ``j``.  ``N`` is zero-padded
+    to ``5 * nb`` first, so padded trits always meet zero activations."""
+    *lead, r, n = x.shape
+    full = nb * TRITS_PER_BYTE
+    if n < full:
+        x = jnp.pad(x, [(0, 0)] * (len(lead) + 1) + [(0, full - n)])
+    x = x.reshape(*lead, r, nb, TRITS_PER_BYTE)
+    return jnp.moveaxis(x, -1, -3)
+
+
+def plane_matmul(plane, p: jax.Array, acc_dtype) -> jax.Array:
+    """``Σ_i plane(i) · trits_i(p)ᵀ`` for one tile.
+
+    ``plane(i)`` loads the ``[bb, bnb]`` activation plane ``i``; ``p`` is the
+    ``[bo, bnb]`` uint8 byte tile.  Returns ``[bb, bo]`` in ``acc_dtype``."""
     v = p.astype(jnp.int32)
-    digs = []
-    for _ in range(TRITS_PER_BYTE):
-        digs.append((v % 3 - 1).astype(out_dtype))
+    acc = None
+    for i in range(TRITS_PER_BYTE):
+        x = plane(i)
+        w = (v % 3 - 1).astype(x.dtype)
         v = v // 3
-    # [bo, bn/5, 5] → [bo, bn]; trit i of byte j is weight 5*j + i.
-    w = jnp.stack(digs, axis=-1)
-    return w.reshape(p.shape[0], -1)
+        part = jax.lax.dot_general(
+            x, w, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=acc_dtype)
+        acc = part if acc is None else acc + part
+    return acc
 
 
-def _dequant_kernel(x_ref, p_ref, out_ref):
-    """x_ref [bb, bn] float; p_ref [bo, bn//5] uint8; out [bb, bo] f32."""
+def _dense_kernel(x_ref, p_ref, out_ref):
+    """x_ref [5, bb, bnb]; p_ref [bo, bnb] uint8; out [bb, bo] (f32/int32)."""
     k = pl.program_id(2)
-    x = x_ref[...]
-    w = _unpack_block(p_ref[...], x.dtype)  # [bo, bn]
-    partial = jax.lax.dot_general(
-        x, w, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += partial
+    out_ref[...] += plane_matmul(lambda i: x_ref[i], p_ref[...],
+                                 out_ref.dtype)
+
+
+def block_bytes(block_n: int, nb: int) -> int:
+    """Packed bytes per reduction step: ``block_n`` trits, capped at the
+    row's ``nb`` bytes (a block equal to the full dim is always legal)."""
+    return max(1, min(block_n // TRITS_PER_BYTE, nb))
+
+
+def packed_call(x, packed, n, *, block_b, block_o, block_n, interpret,
+                acc_dtype):
+    """Pad to blocks, permute to digit planes, and launch the dense kernel.
+
+    x: [B, N] activations; packed: [O, NB] base-3 bytes (NB may carry
+    alignment padding past ``ceil(n/5)``).  Returns [B, O] ``acc_dtype``.
+    Pad *bytes* decode to -1 trits but meet zero activations, so their
+    products vanish; padded B/O rows are sliced off after the call."""
+    B, N = x.shape
+    O, NB = packed.shape
+    if N < n or NB * TRITS_PER_BYTE < n:
+        raise ValueError((N, NB, n))
+    bnb = block_bytes(block_n, NB)
+    block_b = min(block_b, B)
+    block_o = min(block_o, O)
+    pad_b, pad_o, pad_nb = (-B) % block_b, (-O) % block_o, (-NB) % bnb
+    planes = digit_planes(x, NB)                       # [5, B, NB]
+    if pad_b or pad_nb:
+        planes = jnp.pad(planes, ((0, 0), (0, pad_b), (0, pad_nb)))
+    if pad_o or pad_nb:
+        packed = jnp.pad(packed, ((0, pad_o), (0, pad_nb)))
+    Bp, Op, NBp = B + pad_b, O + pad_o, NB + pad_nb
+
+    out = pl.pallas_call(
+        _dense_kernel,
+        grid=(Bp // block_b, Op // block_o, NBp // bnb),
+        in_specs=[
+            pl.BlockSpec((TRITS_PER_BYTE, block_b, bnb),
+                         lambda i, j, k: (0, i, k)),
+            pl.BlockSpec((block_o, bnb), lambda i, j, k: (j, k)),
+        ],
+        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Op), acc_dtype),
+        interpret=resolve_interpret(interpret),
+    )(planes, packed)
+    return out[:B, :O]
 
 
 @functools.partial(
@@ -63,53 +132,19 @@ def packed_matmul(
     *,
     block_b: int = 8,
     block_o: int = 128,
-    block_n: int = 640,  # multiple of 5 (pack group) and 128 (lanes)
-    interpret: bool = True,
+    block_n: int = 640,  # 128 packed bytes: one lane-width of each plane
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """y[b, o] = Σ_n x[b, n] · unpack(packed)[o, n].
+    """y[b, o] = Σ_n x[b, n] · unpack(packed)[o, n], accumulated in f32.
 
     Args:
-      x:      [B, N] activations (N may include padding up to 5·packed cols).
+      x:      [B, N] float activations (N may include padding up to 5·packed
+              cols).
       packed: [O, ceil(N/5)] base-3 packed ternary weights.
       n:      logical N (unpacked columns beyond n are zero by construction).
+      interpret: Pallas interpret mode; ``None`` compiles on TPU and
+              interprets elsewhere.
     """
-    B, N = x.shape
-    O, NB = packed.shape
-    if N < n or NB * TRITS_PER_BYTE < n:
-        raise ValueError((N, NB, n))
-    # pad x to the full unpacked width (pad trits decode to -1? no: pack_base3
-    # zero-pads, and value-0 trits decode to 0, so extra x columns are safely
-    # multiplied by 0; but x itself must cover NB*5 columns)
-    full = NB * TRITS_PER_BYTE
-    if N < full:
-        x = jnp.pad(x, ((0, 0), (0, full - N)))
-    N = full
-
-    block_n = min(block_n, N)
-    block_n -= block_n % TRITS_PER_BYTE
-    block_b = min(block_b, B)
-    block_o = min(block_o, O)
-    pad_b = (-B) % block_b
-    pad_o = (-O) % block_o
-    pad_n = (-N) % block_n
-    if pad_b or pad_n:
-        x = jnp.pad(x, ((0, pad_b), (0, pad_n)))
-    if pad_o or pad_n:
-        packed = jnp.pad(packed, ((0, pad_o), (0, pad_n // TRITS_PER_BYTE)))
-        # note: padded bytes are 0 → trits (-1,-1,-1,-1,-1)… but the matching
-        # x columns are zero-padded, so the products vanish.  Padded *rows*
-        # are sliced off below.
-    Bp, Op, Np = B + pad_b, O + pad_o, N + pad_n
-
-    out = pl.pallas_call(
-        _dequant_kernel,
-        grid=(Bp // block_b, Op // block_o, Np // block_n),
-        in_specs=[
-            pl.BlockSpec((block_b, block_n), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_o, block_n // TRITS_PER_BYTE), lambda i, j, k: (j, k)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Bp, Op), jnp.float32),
-        interpret=interpret,
-    )(x, packed)
-    return out[:B, :O]
+    return packed_call(x, packed, n, block_b=block_b, block_o=block_o,
+                       block_n=block_n, interpret=interpret,
+                       acc_dtype=jnp.float32)

@@ -38,7 +38,10 @@ applied once on the way out.
 On CPU the Pallas kernels run in interpret mode, which is functionally exact
 but orders of magnitude slower than XLA — the prior carries a backend-aware
 penalty so ``auto`` never routes a CPU serving path through an interpreted
-kernel unless the autotune cache has measured otherwise.
+kernel unless the autotune cache has measured otherwise.  On TPU the kernels
+that Mosaic cannot compile (``KernelSpec.tpu=False``: the LUT, sign-flip and
+TL2 Pallas kernels) are never eligible, and under a partitioned serving mesh
+no Pallas kernel is (see :func:`tpu_exclusion`).
 """
 
 from __future__ import annotations
@@ -71,7 +74,7 @@ from repro.kernels.w2a8_matmul import w2a8_matmul
 __all__ = [
     "TernaryWeight", "GroupedTernaryWeight", "KernelSpec", "REGISTRY",
     "register_kernel", "kernel_names", "get_kernel", "eligible_kernels",
-    "select_kernel", "static_prior", "ternary_matmul",
+    "select_kernel", "static_prior", "tpu_exclusion", "ternary_matmul",
     "grouped_ternary_matmul", "autotune",
     "AutotuneCache", "get_autotune_cache", "reset_autotune_cache",
     "ShardInfo", "shard_scope", "current_shard_info",
@@ -354,6 +357,8 @@ class KernelSpec:
     grouped: bool = False             # batched-expert (MoE) kernel
     grouped_variant: str | None = None  # dense kernel's grouped analogue
                                         # (fixed:<dense> pins map through it)
+    tpu: bool = True                  # compiles for TPU (Mosaic/XLA); False
+                                      # kernels are never eligible there
 
     def supports(self, m: int, k: int, n: int, act_dtype: str,
                  e: int | None = None) -> bool:
@@ -390,8 +395,13 @@ def get_kernel(name: str) -> KernelSpec:
 
 
 def eligible_kernels(m: int, k: int, n: int, act_dtype: str,
-                     e: int | None = None) -> list[KernelSpec]:
-    return [s for s in REGISTRY.values() if s.supports(m, k, n, act_dtype, e)]
+                     e: int | None = None,
+                     backend: str | None = None) -> list[KernelSpec]:
+    """Kernels that support the problem; with ``backend``, also only those
+    that can run there (:func:`tpu_exclusion`)."""
+    return [s for s in REGISTRY.values()
+            if s.supports(m, k, n, act_dtype, e)
+            and (backend is None or tpu_exclusion(s, backend) is None)]
 
 
 # -- kernel adapters --------------------------------------------------------
@@ -422,8 +432,10 @@ def _run_lut(fetch):
 
 
 def _run_dequant(x2, w, mu, interpret):
-    return packed_matmul(_to_f32(x2), w.packed(), w.in_features,
-                         interpret=interpret)
+    # bf16 stays bf16 (trits are exact in bf16, accumulation is f32); other
+    # dtypes, int8 included, run the float datapath in f32
+    xk = x2 if x2.dtype == jnp.bfloat16 else _to_f32(x2)
+    return packed_matmul(xk, w.packed(), w.in_features, interpret=interpret)
 
 
 def _run_signflip(x2, w, mu, interpret):
@@ -554,14 +566,16 @@ register_kernel(KernelSpec(
     name="lut_onehot", run=_run_lut("onehot"), act_dtypes=_ALL_DTYPES,
     pallas=True, prior_per_mac=_per_mac_lut, weight_bytes=_bytes_keys,
     describe="two-phase LUT Pallas kernel, MXU-resident signed one-hot fetch",
-    constraint=lambda m, k, n, d: True))
+    constraint=lambda m, k, n, d: True,
+    tpu=False))  # Mosaic: "lhs contracting dims must be of size 1"
 
 register_kernel(KernelSpec(
     name="lut_gather", run=_run_lut("gather"), act_dtypes=_ALL_DTYPES,
     pallas=True, prior_per_mac=_per_mac_lut, weight_bytes=_bytes_keys,
     describe="two-phase LUT Pallas kernel, VPU dynamic-gather fetch "
              "(literal read-out MUX)",
-    constraint=lambda m, k, n, d: True))
+    constraint=lambda m, k, n, d: True,
+    tpu=False))  # Mosaic: "Only 2D gather is supported"
 
 register_kernel(KernelSpec(
     name="dequant_packed", run=_run_dequant, act_dtypes=_ALL_DTYPES,
@@ -572,7 +586,8 @@ register_kernel(KernelSpec(
 register_kernel(KernelSpec(
     name="signflip", run=_run_signflip, act_dtypes=_ALL_DTYPES,
     pallas=True, prior_per_mac=_per_mac_signflip, weight_bytes=_bytes_trits,
-    describe="binary-plane MXU sign-flip baseline (Fig. 1 middle)"))
+    describe="binary-plane MXU sign-flip baseline (Fig. 1 middle)",
+    tpu=False))  # Mosaic: "Target does not support this comparison"
 
 register_kernel(KernelSpec(
     name="w2a8", run=_run_w2a8, act_dtypes=frozenset({"int8"}),
@@ -586,7 +601,8 @@ register_kernel(KernelSpec(
     prior_per_mac=_per_mac_tl2, weight_bytes=_bytes_tl2,
     grouped_variant="grouped_tl2",
     describe="TL2 two-trit → 9-entry LUT Pallas kernel (base-9 uint16 "
-             "packing, 1.6 b/w; bitnet.cpp TL2 / T-MAC idiom)"))
+             "packing, 1.6 b/w; bitnet.cpp TL2 / T-MAC idiom)",
+    tpu=False))  # Mosaic: [bo, 64]-word block not (8, 128)-aligned
 
 register_kernel(KernelSpec(
     name="tl2_ref", run=_run_tl2_ref, act_dtypes=_ALL_DTYPES, pallas=False,
@@ -830,6 +846,11 @@ class ShardInfo:
     n_heads: int = 0
     n_kv_heads: int = 0
 
+    @property
+    def partitioned(self) -> bool:
+        """True when the step is split over more than one device."""
+        return self.model * self.data * self.batch > 1
+
     def local_dense(self, role: str | None, m: int, k: int, n: int):
         from repro.parallel.sharding import (_NO_TP_ROLES, _SPLIT_ROLES,
                                              TP_IN_ROLES, TP_OUT_ROLES)
@@ -898,6 +919,22 @@ def current_shard_info() -> ShardInfo | None:
     return _SHARD_INFO
 
 
+def tpu_exclusion(spec: KernelSpec, backend: str) -> str | None:
+    """Why ``spec`` cannot run on ``backend`` (``None`` when it can).
+
+    An explicit exclusion, not a try/except fallback: on TPU the kernels
+    registered with ``tpu=False`` do not compile with Mosaic, and under a
+    partitioned :func:`shard_scope` no Pallas kernel can run, because GSPMD
+    cannot partition a Mosaic custom call."""
+    if backend != "tpu":
+        return None
+    if not spec.tpu:
+        return "does not compile for TPU"
+    if spec.pallas and _SHARD_INFO is not None and _SHARD_INFO.partitioned:
+        return "is a Mosaic kernel, which GSPMD cannot partition over a mesh"
+    return None
+
+
 # ---------------------------------------------------------------------------
 # Selection + public entry point
 # ---------------------------------------------------------------------------
@@ -961,6 +998,10 @@ def select_kernel(m: int, k: int, n: int, act_dtype: str, *,
                 f"kernel {spec.name!r} does not support M={m} K={k} N={n} "
                 f"E={e} act_dtype={act_dtype} (supported dtypes: "
                 f"{sorted(spec.act_dtypes)}; grouped={spec.grouped})")
+        why = tpu_exclusion(spec, backend)
+        if why is not None:
+            raise ValueError(f"kernel {spec.name!r} {why}; policy "
+                             f"'fixed:{spec.name}' cannot run on {backend}")
         return spec
 
     if policy not in ("auto", "prior"):
@@ -968,27 +1009,24 @@ def select_kernel(m: int, k: int, n: int, act_dtype: str, *,
             f"unknown policy {policy!r}; expected 'auto', 'prior', or "
             f"'fixed:<name>' with name in {sorted(REGISTRY)}")
 
-    candidates = eligible_kernels(m, k, n, act_dtype, e)
+    candidates = eligible_kernels(m, k, n, act_dtype, e, backend)
     if not candidates:
         raise ValueError(f"no registered kernel supports M={m} K={k} N={n} "
-                         f"E={e} act_dtype={act_dtype}")
+                         f"E={e} act_dtype={act_dtype} on {backend}")
 
     if policy == "auto":
         cache = cache or get_autotune_cache()
         best = cache.best(m, k, n, act_dtype, backend, mu=mu, e=e)
-        if best is not None and get_kernel(best).supports(m, k, n, act_dtype, e):
+        if best is not None and get_kernel(best) in candidates:
             return get_kernel(best)
 
+    # equal priors go to the kernel specialised for fewer activation dtypes
+    # (w2a8's integer MXU path over dequant_packed's f32 one on int8); the
     # name tiebreak keeps selection deterministic across dict orderings
     return min(candidates,
                key=lambda s: (static_prior(s, m, k, n, act_dtype, backend,
                                            mu, e),
-                              s.name))
-
-
-def _default_interpret() -> bool:
-    """Pallas interpret mode everywhere except real TPU hardware."""
-    return jax.default_backend() != "tpu"
+                              len(s.act_dtypes), s.name))
 
 
 def ternary_matmul(x: jax.Array, w, *, scale=None, policy: str | None = None,
@@ -1032,8 +1070,6 @@ def ternary_matmul(x: jax.Array, w, *, scale=None, policy: str | None = None,
 
     spec = select_kernel(m, k, n, act, policy=policy, backend=backend,
                          cache=cache, mu=mu, role=role)
-    if interpret is None:
-        interpret = _default_interpret()
     y = spec.run(x2, tw, mu, interpret)
     s = tw.scale if scale is None else scale
     if s is not None:
@@ -1084,8 +1120,6 @@ def grouped_ternary_matmul(x: jax.Array, w, *, scale=None,
 
     spec = select_kernel(c, k, n, act, policy=policy, backend=backend,
                          cache=cache, mu=mu, e=E, role=role)
-    if interpret is None:
-        interpret = _default_interpret()
     y = spec.run(x3, gw, mu, interpret)
     s = gw.scale if scale is None else scale
     if s is not None:
@@ -1118,6 +1152,10 @@ def autotune(m: int, k: int, n: int, act_dtype: str = "float32", *,
     per-expert capacity ``C``, operands are stacked ``[e, m, k]`` acts ×
     ``[e, n, ceil(k/5)]`` packed, and only grouped kernels run.
 
+    A kernel that fails is skipped with a warning off TPU (a backend it was
+    not written for); on TPU, where only kernels that compile there are
+    eligible, the failure is raised.
+
     Returns ``{kernel_name: µs}``.  Subsequent ``policy="auto"`` dispatches
     for the same ``(M, K, N, dtype, backend)`` (+ ``E`` if grouped) use the
     measured best.
@@ -1131,8 +1169,6 @@ def autotune(m: int, k: int, n: int, act_dtype: str = "float32", *,
         # backend's cache key would poison that backend's auto dispatch
         raise ValueError(f"autotune measures on the local backend {local!r}; "
                          f"cannot record for backend={backend!r}")
-    if interpret is None:
-        interpret = _default_interpret()
     cache = cache or get_autotune_cache()
     rng = np.random.default_rng(seed)
     lead = () if e is None else (e,)
@@ -1143,8 +1179,8 @@ def autotune(m: int, k: int, n: int, act_dtype: str = "float32", *,
     packed = encoding.pack_base3(
         jnp.asarray(rng.integers(-1, 2, size=(*lead, n, k)), jnp.int8))
 
-    names = kernels or [s.name
-                        for s in eligible_kernels(m, k, n, act_dtype, e)]
+    names = kernels or [s.name for s in
+                        eligible_kernels(m, k, n, act_dtype, e, backend)]
     results: dict[str, float] = {}
     for name in names:
         spec = get_kernel(name)
@@ -1163,7 +1199,11 @@ def autotune(m: int, k: int, n: int, act_dtype: str = "float32", *,
                 y = fn(x, packed)
             jax.block_until_ready(y)
             us = (time.perf_counter() - t0) / reps * 1e6
-        except Exception as exc:  # pragma: no cover - kernel unavailable on backend
+        except Exception as exc:
+            if backend == "tpu":
+                # every kernel eligible on TPU must compile and run there; a
+                # failure is a bug to surface, not a timing to skip
+                raise
             tag = f"E{e} " if e is not None else ""
             warnings.warn(f"autotune: kernel {name!r} failed on "
                           f"{tag}M{m} K{k} N{n} {act_dtype}/{backend}: {exc}")

@@ -39,70 +39,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.encoding import TRITS_PER_BYTE
-from repro.kernels.dequant_matmul import _unpack_block
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.dequant_matmul import block_bytes, digit_planes, plane_matmul
 
 
-def _grouped_kernel(acc_dtype):
-    def kernel(x_ref, p_ref, out_ref):
-        """x_ref [1, bc, bn]; p_ref [1, bo, bn//5]; out [1, bc, bo]."""
-        k = pl.program_id(3)
-        x = x_ref[0]
-        w = _unpack_block(p_ref[0], x.dtype)  # [bo, bn] trits in act dtype
-        partial = jax.lax.dot_general(
-            x, w, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype,
-        )
+def _grouped_kernel(x_ref, p_ref, out_ref):
+    """x_ref [1, 5, bc, bnb]; p_ref [1, bo, bnb] uint8; out [1, bc, bo]."""
+    k = pl.program_id(3)
 
-        @pl.when(k == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
+    @pl.when(k == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-        out_ref[0] += partial
-
-    return kernel
+    out_ref[0] += plane_matmul(lambda i: x_ref[0, i], p_ref[0],
+                               out_ref.dtype)
 
 
 def _pad_and_call(x, packed, *, block_c, block_o, block_n, interpret,
                   acc_dtype):
     """Shared pad-to-blocks + pallas_call for both grouped variants.
 
-    x: [E, C, N]; packed: [E, O, ceil(N/5)].  Returns [E, C, O] acc_dtype.
-    Padding follows the dense kernels' scheme: x columns zero-pad to the full
-    unpacked width (pad *bytes* decode to -1 trits but meet zero activations,
-    so products vanish); padded C/O rows are sliced off after the call.
+    x: [E, C, N]; packed: [E, O, NB].  Returns [E, C, O] acc_dtype.
+    Padding and the digit-plane layout follow the dense kernels
+    (``dequant_matmul.packed_call``): pad bytes meet zero activations, and
+    padded C/O rows are sliced off after the call.
     """
-    E, C, N = x.shape
+    E, C, _ = x.shape
     _, O, NB = packed.shape
-    full = NB * TRITS_PER_BYTE
-    if N < full:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, full - N)))
-    N = full
-    block_n = min(block_n, N)
-    block_n -= block_n % TRITS_PER_BYTE
+    bnb = block_bytes(block_n, NB)
     block_c = min(block_c, C)
     block_o = min(block_o, O)
-    pad_c, pad_o, pad_n = (-C) % block_c, (-O) % block_o, (-N) % block_n
-    if pad_c or pad_n:
-        x = jnp.pad(x, ((0, 0), (0, pad_c), (0, pad_n)))
-    if pad_o or pad_n:
-        packed = jnp.pad(packed,
-                         ((0, 0), (0, pad_o), (0, pad_n // TRITS_PER_BYTE)))
-    Cp, Op, Np = C + pad_c, O + pad_o, N + pad_n
+    pad_c, pad_o, pad_nb = (-C) % block_c, (-O) % block_o, (-NB) % bnb
+    planes = digit_planes(x, NB)                       # [E, 5, C, NB]
+    if pad_c or pad_nb:
+        planes = jnp.pad(planes, ((0, 0), (0, 0), (0, pad_c), (0, pad_nb)))
+    if pad_o or pad_nb:
+        packed = jnp.pad(packed, ((0, 0), (0, pad_o), (0, pad_nb)))
+    Cp, Op, NBp = C + pad_c, O + pad_o, NB + pad_nb
 
     out = pl.pallas_call(
-        _grouped_kernel(acc_dtype),
-        grid=(E, Cp // block_c, Op // block_o, Np // block_n),
+        _grouped_kernel,
+        grid=(E, Cp // block_c, Op // block_o, NBp // bnb),
         in_specs=[
-            pl.BlockSpec((1, block_c, block_n),
-                         lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((1, block_o, block_n // TRITS_PER_BYTE),
-                         lambda e, i, j, k: (e, j, k)),
+            pl.BlockSpec((1, TRITS_PER_BYTE, block_c, bnb),
+                         lambda e, i, j, k: (e, 0, i, k)),
+            pl.BlockSpec((1, block_o, bnb), lambda e, i, j, k: (e, j, k)),
         ],
         out_specs=pl.BlockSpec((1, block_c, block_o),
                                lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, Op), acc_dtype),
-        interpret=interpret,
-    )(x, packed)
+        interpret=resolve_interpret(interpret),
+    )(planes, packed)
     return out[:, :C, :O]
 
 
@@ -116,8 +103,8 @@ def grouped_packed_matmul(
     *,
     block_c: int = 8,
     block_o: int = 128,
-    block_n: int = 640,  # multiple of 5 (pack group) and 128 (lanes)
-    interpret: bool = True,
+    block_n: int = 640,  # 128 packed bytes: one lane-width of each plane
+    interpret: bool | None = None,
 ) -> jax.Array:
     """y[e, c, o] = Σ_n x[e, c, n] · unpack(packed[e])[o, n] (f32).
 
@@ -148,7 +135,7 @@ def grouped_w2a8_matmul(
     block_c: int = 8,
     block_o: int = 128,
     block_n: int = 640,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Exact int32 y[e, c, o] = Σ_n x_q[e, c, n] · trits(packed[e])[o, n].
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import encoding
+from repro.kernels.backend import resolve_interpret
 
 
 def _lut_kernel(x_ref, keys_ref, out_ref, *, mu: int, fetch: str):
@@ -102,7 +103,7 @@ def lut_matmul(
     block_o: int = 128,
     block_g: int = 128,
     fetch: str = "onehot",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Two-phase LUT matmul: ``y[b, o] = Σ_n x[b, n] · decode(keys)[o, n]``.
 
@@ -112,8 +113,8 @@ def lut_matmul(
       mu:   LUT group size.
       block_*: VMEM tile sizes (the generator's KernelPlan supplies aligned
         values for real TPU; tests shrink them).
-      interpret: run the kernel body in interpret mode (CPU container);
-        False targets real TPU hardware.
+      interpret: run the kernel body in interpret mode; ``None`` compiles
+        on TPU and interprets elsewhere.
 
     Returns:
       [B, O] float32.
@@ -148,6 +149,6 @@ def lut_matmul(
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Op), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, keys)
     return out[:B, :O]

@@ -8,11 +8,12 @@ INT8 activation scale are rank-1 corrections applied outside the hot loop).
   * ``"lut"``      — two-phase LUT kernel (paper's architecture),
   * ``"signflip"`` — binary-plane MXU baseline (Fig. 1 middle),
   * ``"dequant"``  — packed 1.6-bit streaming dequant (deployment path),
-all validated against ``ref.py`` in ``tests/test_kernels.py``.
+all validated against ``ref.py`` in ``tests/test_kernels.py``.  Only the
+packed path compiles for TPU; the LUT and sign-flip kernels run in the
+interpreter (see ``dispatch.KernelSpec.tpu``).
 
-On this CPU container kernels run with ``interpret=True``; on real TPU pass
-``interpret=False`` (the launch geometry comes from the generator's
-KernelPlan).
+``interpret=None`` (the default) compiles the kernels with Mosaic on TPU and
+runs them in the Pallas interpreter on every other backend.
 """
 
 from __future__ import annotations
@@ -33,9 +34,9 @@ def _flatten_batch(x):
     return x.reshape(-1, x.shape[-1]), lead
 
 
-def ternary_linear_lut(x, keys, scale, mu: int, *, interpret: bool = True,
-                       fetch: str = "onehot", block_o: int = 128,
-                       block_g: int = 128):
+def ternary_linear_lut(x, keys, scale, mu: int, *,
+                       interpret: bool | None = None, fetch: str = "onehot",
+                       block_o: int = 128, block_g: int = 128):
     """y = (x @ decode(keys).T) * scale via the LUT kernel.  x: [..., N]."""
     x2, lead = _flatten_batch(x)
     y = lut_matmul(x2.astype(jnp.float32), keys, mu, fetch=fetch,
@@ -44,14 +45,15 @@ def ternary_linear_lut(x, keys, scale, mu: int, *, interpret: bool = True,
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
-def ternary_linear_signflip(x, w_t, scale, *, interpret: bool = True):
+def ternary_linear_signflip(x, w_t, scale, *, interpret: bool | None = None):
     x2, lead = _flatten_batch(x)
     y = signflip_matmul(x2.astype(jnp.float32), w_t, interpret=interpret)
     y = y * jnp.asarray(scale, jnp.float32)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
-def ternary_linear_packed(x, packed, scale, n: int, *, interpret: bool = True):
+def ternary_linear_packed(x, packed, scale, n: int, *,
+                          interpret: bool | None = None):
     x2, lead = _flatten_batch(x)
     y = packed_matmul(x2.astype(jnp.float32), packed, n, interpret=interpret)
     y = y * jnp.asarray(scale, jnp.float32)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _signflip_kernel(x_ref, w_ref, out_ref):
     """x_ref [bb, bn] float; w_ref [bo, bn] int8 trits; out_ref [bb, bo] f32."""
@@ -52,7 +54,7 @@ def signflip_matmul(
     block_b: int = 8,
     block_o: int = 128,
     block_n: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """y[b, o] = Σ_n x[b, n]·w_t[o, n] with w_t ∈ {-1,0,1} (int8), no multiplies."""
     B, N = x.shape
@@ -79,6 +81,6 @@ def signflip_matmul(
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Op), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_t)
     return out[:B, :O]

@@ -35,6 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.encoding import TRITS_PER_BYTE, unpack_base3
+from repro.kernels.backend import resolve_interpret
 
 #: base-9 digits per packed uint16 word
 PAIRS_PER_WORD = 5
@@ -181,7 +182,7 @@ def tl2_matmul(
     block_b: int = 8,
     block_o: int = 128,
     block_w: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Pallas TL2 matmul: ``y[b, o] = Σ_k x[b, k] · trits(words)[o, k]``.
 
@@ -191,7 +192,8 @@ def tl2_matmul(
       words: [O, W] uint16 TL2-packed ternary weights (:func:`pack_tl2`).
       n:     logical K (columns beyond n are zero by construction).
       block_*: VMEM tile sizes; ``block_w`` counts packed words (×10 x cols).
-      interpret: interpret mode (CPU container); False targets real TPU.
+      interpret: interpret mode; ``None`` compiles on TPU and interprets
+             elsewhere.
 
     Returns [B, O] float32.
     """
@@ -227,6 +229,6 @@ def tl2_matmul(
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Op), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, words)
     return out[:B, :O]

@@ -21,27 +21,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.encoding import TRITS_PER_BYTE
-from repro.kernels.dequant_matmul import _unpack_block
-
-
-def _w2a8_kernel(x_ref, p_ref, out_ref):
-    """x_ref [bb, bn] int8; p_ref [bo, bn//5] uint8; out [bb, bo] int32."""
-    k = pl.program_id(2)
-    x = x_ref[...]
-    w = _unpack_block(p_ref[...], jnp.int8)  # [bo, bn] trits
-    partial = jax.lax.dot_general(
-        x, w, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += partial
+from repro.kernels.dequant_matmul import packed_call
 
 
 @functools.partial(
@@ -55,46 +36,25 @@ def w2a8_matmul(
     block_b: int = 8,
     block_o: int = 128,
     block_n: int = 640,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Exact int32 y[b,o] = Σ_n x_q[b,n] · trits(packed)[o,n].
 
     x_q: [B, N] int8 (per-token quantized activations).
     packed: [O, ceil(N/5)] base-3 ternary weights.
-    """
-    B, N = x_q.shape
-    O, NB = packed.shape
-    full = NB * TRITS_PER_BYTE
-    if N < full:
-        x_q = jnp.pad(x_q, ((0, 0), (0, full - N)))
-    N = full
-    block_n = min(block_n, N)
-    block_n -= block_n % TRITS_PER_BYTE
-    block_b = min(block_b, B)
-    block_o = min(block_o, O)
-    pad_b, pad_o, pad_n = (-B) % block_b, (-O) % block_o, (-N) % block_n
-    if pad_b or pad_n:
-        x_q = jnp.pad(x_q, ((0, pad_b), (0, pad_n)))
-    if pad_o or pad_n:
-        packed = jnp.pad(packed, ((0, pad_o), (0, pad_n // TRITS_PER_BYTE)))
-    Bp, Op, Np = B + pad_b, O + pad_o, N + pad_n
 
-    out = pl.pallas_call(
-        _w2a8_kernel,
-        grid=(Bp // block_b, Op // block_o, Np // block_n),
-        in_specs=[
-            pl.BlockSpec((block_b, block_n), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_o, block_n // TRITS_PER_BYTE), lambda i, j, k: (j, k)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Bp, Op), jnp.int32),
-        interpret=interpret,
-    )(x_q, packed)
-    return out[:B, :O]
+    The packed-streaming kernel of :mod:`repro.kernels.dequant_matmul` with
+    trits expanded to int8 and an int32 accumulator.
+    """
+    if x_q.dtype != jnp.int8:
+        raise TypeError(f"w2a8_matmul needs int8 activations, got {x_q.dtype}")
+    return packed_call(x_q, packed, n, block_b=block_b, block_o=block_o,
+                       block_n=block_n, interpret=interpret,
+                       acc_dtype=jnp.int32)
 
 
 def w2a8_linear(x: jax.Array, packed: jax.Array, w_scale: jax.Array, n: int,
-                *, interpret: bool = True) -> jax.Array:
+                *, interpret: bool | None = None) -> jax.Array:
     """Full W1.58A8 linear: quantize acts → int kernel → rank-1 rescale."""
     from repro.core.quantization import quantize_activations_int8
 

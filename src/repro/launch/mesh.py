@@ -8,19 +8,27 @@ so importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: JAX ≥0.9 defaults to Explicit axes,
+    under which the serving path's gathers (embedding, base-3 decode table)
+    raise ``ShardingTypeError``; GSPMD propagation is what this repo uses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_mesh_for(devices: int, model_parallel: int = 1):
     """Small meshes for tests/examples on real local devices."""
     assert devices % model_parallel == 0
-    return jax.make_mesh((devices // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _make_mesh((devices // model_parallel, model_parallel),
+                      ("data", "model"))
 
 
 def make_serving_mesh(spec: str):
@@ -51,7 +59,7 @@ def make_serving_mesh(spec: str):
         raise ValueError(
             f"mesh {spec!r} needs {n} devices but {avail} are available "
             "(on CPU, set XLA_FLAGS=--xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 # Hardware constants for the roofline (assignment block).

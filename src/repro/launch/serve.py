@@ -58,10 +58,57 @@ import jax
 
 from repro.checkpoint import checkpointing as ckpt
 from repro.configs.registry import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.decode import packed_bits_per_weight, quantize_for_serving
 from repro.models.model import init_params
 from repro.serving.engine import DecodeEngine, Request, SamplerConfig
 from repro.serving.scheduler import ContinuousScheduler
+
+
+def describe_devices() -> dict:
+    """The devices JAX runs on, as JAX reports them."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def load_served(cfg, *, seed: int = 0, ckpt_dir: str | None = None):
+    """Initialize params from ``seed`` (or restore the latest checkpoint in
+    ``ckpt_dir``) and quantize them to the packed serving artifact.
+
+    Each path is one jitted program: op by op, init and quantization
+    compile one executable per op and keep every f32 temporary of the
+    ternarize/pack passes alive at once (13.8 GB peak for bitnet-b1.58-2b
+    on a 16 GB v5e)."""
+    key = jax.random.PRNGKey(seed)
+    if ckpt_dir:
+        step, state = ckpt.restore_latest(
+            ckpt_dir, {"params": jax.eval_shape(lambda: init_params(cfg, key))})
+        if state is not None:
+            print(f"[serve] restored step {step} from {ckpt_dir}")
+            return jax.jit(lambda p: quantize_for_serving(p, cfg))(
+                state["params"])
+    return jax.jit(lambda k: quantize_for_serving(init_params(cfg, k), cfg))(
+        key)
+
+
+def serve_requests(engine: DecodeEngine, reqs: list[Request], *,
+                   discipline: str = "continuous", on_token=None,
+                   admission_budget: int | None = None,
+                   dynamic_spec_k: bool = False):
+    """Serve ``reqs`` to completion on ``engine``; their ``out`` lists fill
+    in place.  Returns ``(decode_steps, scheduler)`` — the scheduler is
+    None on the generational path."""
+    if discipline == "generational":
+        engine.run(reqs)
+        return max(len(r.out) for r in reqs), None
+    sched = ContinuousScheduler(engine, on_token=on_token,
+                                admission_budget=admission_budget,
+                                dynamic_spec_k=dynamic_spec_k)
+    for r in reqs:
+        sched.submit(r)
+    sched.run()
+    return sched.stats.decode_steps, sched
 
 
 def main():
@@ -126,19 +173,12 @@ def main():
                     "[2, --spec-k]")
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    print(f"[serve] devices {describe_devices()}; compile cache {cache_dir}")
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.act_dtype != "none":
         cfg = cfg.with_(act_dtype=args.act_dtype)
-    key = jax.random.PRNGKey(0)
-    params = init_params(cfg, key)
-    if args.ckpt_dir:
-        step, state = ckpt.restore_latest(
-            args.ckpt_dir, jax.eval_shape(lambda: {"params": params}))
-        if state is not None:
-            params = state["params"]
-            print(f"[serve] restored step {step} from {args.ckpt_dir}")
-
-    served = quantize_for_serving(params, cfg)
+    served = load_served(cfg, ckpt_dir=args.ckpt_dir)
     print(f"[serve] {cfg.name}: packed {packed_bits_per_weight(served):.3f} b/w")
     mesh = None
     if args.mesh:
@@ -155,8 +195,7 @@ def main():
                              "the draft)")
         dcfg = (get_smoke_config(args.draft) if args.smoke
                 else get_config(args.draft))
-        draft_params = quantize_for_serving(
-            init_params(dcfg, jax.random.PRNGKey(1)), dcfg)
+        draft_params = load_served(dcfg, seed=1)
         print(f"[serve] draft {dcfg.name}: spec_k={args.spec_k}, packed "
               f"{packed_bits_per_weight(draft_params):.3f} b/w")
         draft = (draft_params, dcfg)
@@ -184,12 +223,14 @@ def main():
                           prefix_cache=args.prefix_cache,
                           prefix_cache_mb=args.prefix_cache_mb,
                           draft=draft, spec_k=args.spec_k)
+    for (entry, role, m, k, n), name in engine.kernel_choices().items():
+        print(f"[serve] kernel {entry} {role} M{m} K{k} N{n}: {name}")
+    budget = args.admission_budget if args.admission_budget > 0 else None
     if scenario is not None:
         from repro.serving.loadgen import (LoadGenerator, generate_trace,
                                            latency_summary)
 
         trace = generate_trace(scenario, cfg.vocab_size, args.scenario_seed)
-        budget = args.admission_budget if args.admission_budget > 0 else None
         gen = LoadGenerator(engine, trace, clock="wall",
                             admission_budget=budget,
                             dynamic_spec_k=args.dynamic_spec_k)
@@ -217,30 +258,22 @@ def main():
     reqs = [Request(prompt=[7 + i, 13 + i], max_new_tokens=args.new_tokens)
             for i in range(n_req)]
 
+    if args.discipline == "generational" and n_req > args.batch:
+        raise SystemExit("[serve] generational cannot queue: "
+                         "--requests must be <= --batch")
+    ids = {r.rid: i for i, r in enumerate(reqs)}
+    on_token = (lambda r, t: print(f"  [stream] req {ids[r.rid]}: {t}")) \
+        if args.stream else None
     t0 = time.time()
-    if args.discipline == "generational":
-        if n_req > args.batch:
-            raise SystemExit("[serve] generational cannot queue: "
-                             "--requests must be <= --batch")
-        engine.run(reqs)
-        steps = max(len(r.out) for r in reqs)
-    else:
-        ids = {r.rid: i for i, r in enumerate(reqs)}
-        on_token = (lambda r, t: print(f"  [stream] req {ids[r.rid]}: {t}")) \
-            if args.stream else None
-        budget = args.admission_budget if args.admission_budget > 0 else None
-        sched = ContinuousScheduler(engine, on_token=on_token,
-                                    admission_budget=budget,
-                                    dynamic_spec_k=args.dynamic_spec_k)
-        for r in reqs:
-            sched.submit(r)
-        sched.run()
-        steps = sched.stats.decode_steps
+    steps, sched = serve_requests(
+        engine, reqs, discipline=args.discipline, on_token=on_token,
+        admission_budget=budget,
+        dynamic_spec_k=args.dynamic_spec_k)
     dt = time.time() - t0
     n = sum(len(r.out) for r in reqs)
     print(f"[serve] {args.discipline}: {n} tokens / {steps} decode steps "
           f"in {dt:.1f}s ({n / dt:.1f} tok/s)")
-    if args.draft and args.discipline == "continuous":
+    if args.draft and sched is not None:
         st = sched.stats
         print(f"[serve] speculative: {st.spec_rounds} rounds, "
               f"{st.accepted_drafted_tokens}/{st.drafted_tokens} drafted "

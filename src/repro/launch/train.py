@@ -40,6 +40,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import checkpointing as ckpt
 from repro.configs.registry import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, SyntheticLMStream
+from repro.launch.mesh import make_mesh_for
 from repro.models.model import init_params, train_loss
 from repro.optim import compression
 from repro.optim.optimizers import clip_by_global_norm, make_optimizer
@@ -162,9 +163,7 @@ def main():
     args = ap.parse_args()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    n = jax.device_count()
-    mesh = jax.make_mesh((n, 1), ("data", "model")) if n > 1 else \
-        jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh_for(jax.device_count())
     out = train(cfg, steps=args.steps, global_batch=args.global_batch,
                 seq_len=args.seq_len, mesh=mesh, ckpt_dir=args.ckpt_dir,
                 checkpoint_every=args.checkpoint_every, resume=args.resume,

@@ -543,6 +543,25 @@ class DecodeEngine:
                                 "draft_prefill_chunk"))
         return sources
 
+    def kernel_choices(self) -> dict[tuple[str, str, int, int, int], str]:
+        """The kernel dispatch selects for every dense projection of this
+        engine's steady-state traces: ``{(entry, role, M, K, N): name}``
+        with global dims, resolved exactly as the traced matmuls resolve
+        them — same policy, activation dtype, backend and shard scope."""
+        from repro.kernels.dispatch import select_kernel, shard_scope
+        from repro.models.decode import layer_matmul_problems
+
+        out = {}
+        for c, bs, sl, entry in self._shape_sources():
+            act = "int8" if c.act_dtype == "int8" else c.dtype
+            with shard_scope(self._shard_infos.get(entry)):
+                for role, m, k, n in layer_matmul_problems(c, bs,
+                                                           seq_len=sl):
+                    out[(entry, role, m, k, n)] = select_kernel(
+                        m, k, n, act, policy=c.matmul_policy,
+                        mu=c.mu, role=role).name
+        return out
+
     def autotune_shapes(self, *, include_prefill: bool = True,
                         **autotune_kw) -> dict:
         """Populate the dispatch autotune cache for this engine's per-step
@@ -904,6 +923,11 @@ class DecodeEngine:
             chunks.append((jnp.asarray(toks), jnp.asarray(pos),
                            jnp.asarray(valid - 1, jnp.int32)))
         cache1 = init_cache(self.cfg, 1, self.max_len)
+        if self.mesh is not None:
+            # place the fresh cache in the layout the chunk fn returns: JAX
+            # keys jit traces on input shardings, so an unplaced first chunk
+            # would trace apart from every later one
+            cache1 = jax.device_put(cache1, self._cache1_sh)
         hits, hashes = 0, []
         if self.prefix_store is not None:
             store = self.prefix_store

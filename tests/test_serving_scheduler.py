@@ -477,9 +477,13 @@ def test_continuous_stop_token_matches_oracle(key):
     """A stop token must free the slot at the same step the oracle stops."""
     eng = _tiny_engine(key, B=2)
     base = _single_request_oracle(eng, [3, 4, 5], 6)
-    stop = base[1]  # greedy 2nd token — learned, so the test is model-free
+    # the first greedy token after the first that has not appeared before —
+    # learned, so the test is model-free (a repeat of an earlier token
+    # would stop the stream there instead)
+    j = next(i for i in range(1, len(base)) if base[i] not in base[:i])
+    stop = base[j]
     want = _single_request_oracle(eng, [3, 4, 5], 6, stop=stop)
-    assert want == base[:2]
+    assert want == base[:j + 1]
     r = Request(prompt=[3, 4, 5], max_new_tokens=6, stop_token=stop)
     other = Request(prompt=[7], max_new_tokens=4)
     eng.serve([r, other], max_steps=200)

@@ -10,22 +10,14 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs.registry import ARCHS, input_specs
 from repro.configs.shapes import cells_for
+from repro.launch.mesh import make_mesh_for
 from repro.models.decode import quantize_for_serving
 from repro.models.model import init_params
 from repro.optim.optimizers import make_optimizer
 from repro.parallel import sharding as sh
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across JAX versions: new signature takes (name, size)
-    pairs; pre-0.4.36 took (shape_tuple, axis_names)."""
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
-
-
-MESHES = [_abstract_mesh((16, 16), ("data", "model")),
-          _abstract_mesh((2, 16, 16), ("pod", "data", "model"))]
+MESHES = [AbstractMesh((16, 16), ("data", "model")),
+          AbstractMesh((2, 16, 16), ("pod", "data", "model"))]
 
 
 def _check_divisible(tree_sds, tree_specs, mesh):
@@ -110,7 +102,7 @@ def test_param_specs_golden_packed_moe(key):
     replicates: 1 head doesn't divide model=4)."""
     from repro.configs.registry import get_smoke_config
 
-    mesh = _abstract_mesh((2, 4), ("data", "model"))
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     cfg = get_smoke_config("phi3.5-moe-42b-a6.6b").with_(n_layers=2)
     assert cfg.n_heads % 4 == 0 and cfg.n_kv_heads == 1
     sds = jax.eval_shape(functools.partial(init_params, cfg), key)
@@ -143,7 +135,7 @@ def test_cache_specs_kv_head_gated():
     """Serving KV cache with ``kv_heads``: shard the head dim (whole heads),
     falling back to replication when the head count doesn't divide model —
     never the intra-head hd dim."""
-    mesh = _abstract_mesh((2, 4), ("data", "model"))
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     kv = {"k": jax.ShapeDtypeStruct((2, 4, 64, 8, 32), jnp.bfloat16),
           "v": jax.ShapeDtypeStruct((2, 4, 64, 8, 32), jnp.bfloat16),
           "pos": jax.ShapeDtypeStruct((4,), jnp.int32)}
@@ -163,8 +155,8 @@ def test_wz_partial_replication_gate():
     """wz (mamba2's elementwise gate projection) is TP'd only on a
     pure-model mesh; with a real batch axis alongside model it replicates
     (sharding._NO_TP_ROLES — CPU SPMD partial-replication miscompile)."""
-    mixed = _abstract_mesh((2, 4), ("data", "model"))
-    pure = _abstract_mesh((1, 8), ("data", "model"))
+    mixed = AbstractMesh((2, 4), ("data", "model"))
+    pure = AbstractMesh((1, 8), ("data", "model"))
     path = ("blocks", "ssm", "wz", "w")
     assert sh._param_spec(path, 2, mixed) == P()
     assert sh._param_spec(path, 2, pure) == P(None, "model")
@@ -184,7 +176,7 @@ def test_small_scale_jit_with_shardings(key):
     from repro.models.model import train_loss
 
     cfg = get_smoke_config("qwen3-0.6b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh_for(1)
     params = init_params(cfg, key)
     psh = sh.to_shardings(sh.param_specs(params, mesh), mesh)
     batch = {"tokens": jnp.ones((2, 16), jnp.int32),

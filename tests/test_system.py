@@ -8,13 +8,14 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_smoke_config
+from repro.launch.mesh import make_mesh_for
 from repro.launch.train import train
 from repro.models.decode import quantize_for_serving
 from repro.serving.engine import DecodeEngine, Request
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh_for(1)
 
 
 def test_train_loss_decreases():
