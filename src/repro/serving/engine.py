@@ -42,6 +42,17 @@ from repro.models.decode import (
 
 logger = logging.getLogger(__name__)
 
+#: the serving loop's profiler spans (``jax.profiler.TraceAnnotation``
+#: names): ``ContinuousScheduler`` opens the first five, ``DecodeEngine``'s
+#: ``sched_step`` the last two inside ``serve.decode``.  ``serve.step``
+#: carries ``live``, ``queued`` and ``prefilling`` (counts at its start),
+#: ``serve.admit`` ``rid`` and ``prompt_len``, ``serve.prefill_chunk``
+#: ``rid``.  No span opens inside a jitted program: each compiled entry
+#: point runs as one device program, named ``jit_<entry point>`` (see
+#: ``DecodeEngine._counted``).
+SPANS = ("serve.step", "serve.admit", "serve.prefill_chunk", "serve.decode",
+         "serve.deliver", "serve.decode.launch", "serve.decode.wait")
+
 
 @dataclass
 class SamplerConfig:
@@ -473,7 +484,9 @@ class DecodeEngine:
         ``trace_counts[name]`` — cache hits never re-enter the wrapper.
         In mesh mode the trace also runs under the entry point's
         ``dispatch.shard_scope``, so every ternary-matmul selection inside
-        keys on the per-device local problem."""
+        keys on the per-device local problem.  The wrapper takes the entry
+        point's name, which ``jax.jit`` gives the compiled program: a
+        profiler trace shows ``jit_sched_step``, ``jit_prefill_chunk``..."""
         info = self._shard_infos.get(name)
 
         def wrapped(*args):
@@ -482,6 +495,7 @@ class DecodeEngine:
             self.trace_counts[name] += 1
             with shard_scope(info):
                 return fn(*args)
+        wrapped.__name__ = wrapped.__qualname__ = name
         return wrapped
 
     def matmul_shape_universe(self, *, include_prefill: bool = True
@@ -1050,9 +1064,12 @@ class DecodeEngine:
         return state
 
     def sched_step(self, state: dict):
-        self._key, k = jax.random.split(self._key)
-        state, toks, alive = self._sched_step_fn(self.params, state, k)
-        return state, np.asarray(toks), np.asarray(alive)
+        with jax.profiler.TraceAnnotation("serve.decode.launch"):
+            self._key, k = jax.random.split(self._key)
+            state, toks, alive = self._sched_step_fn(self.params, state, k)
+        # the host blocks here until the step's program has run
+        with jax.profiler.TraceAnnotation("serve.decode.wait"):
+            return state, np.asarray(toks), np.asarray(alive)
 
     #: sched_spec_step accepts per-slot draft windows (dynamic spec_k)
     spec_window_aware = True

@@ -25,7 +25,12 @@ scheduler here keeps every slot busy instead:
     and liveness mask are computed inside the backend's jitted step, so the
     decode loop never branches on the host per token; the host reads back
     one small ``(tokens, alive)`` pair per step to drive streaming and
-    refills.
+    refills;
+  * **profiler spans** — each step and its phases open a
+    ``jax.profiler.TraceAnnotation`` (names in
+    :data:`repro.serving.engine.SPANS`), so a profiler trace shows what the
+    host was doing while the device idled; with no profiler session open a
+    span costs about a microsecond.
 
 The scheduler is pure host-side bookkeeping over a narrow backend protocol
 (:class:`ScheduleBackend`), implemented for real models by
@@ -40,6 +45,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Protocol, runtime_checkable
+
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import Request
 
@@ -318,27 +325,29 @@ class ContinuousScheduler:
             if self.slots[slot] is not None or slot in self.prefilling:
                 continue
             while self.queue:
-                req = self._pop_next()
-                if req.max_new_tokens <= 0:  # zero-budget: completes at once
-                    req.done = True
-                    self._enqueue_t.pop(req.rid, None)
-                    self.completed.append(req)
-                    self.stats.completed += 1
-                    continue
-                if start is None:  # atomic-admission backend
-                    self._state = self.backend.sched_admit(self._state, slot,
-                                                           req)
-                    self.slots[slot] = req
-                else:
-                    self._state, pend = start(self._state, slot, req)
-                    if pend is None:
+                with TraceAnnotation("serve.admit") as span:
+                    req = self._pop_next()
+                    span.set_metadata(rid=req.rid, prompt_len=len(req.prompt))
+                    if req.max_new_tokens <= 0:  # zero-budget: done at once
+                        req.done = True
+                        self._enqueue_t.pop(req.rid, None)
+                        self.completed.append(req)
+                        self.stats.completed += 1
+                        continue
+                    if start is None:  # atomic-admission backend
+                        self._state = self.backend.sched_admit(self._state,
+                                                               slot, req)
                         self.slots[slot] = req
                     else:
-                        self.prefilling[slot] = (req, pend)
-                self.admission_order.append(req)
-                self._record_admission(req)
-                self.stats.admitted += 1
-                break
+                        self._state, pend = start(self._state, slot, req)
+                        if pend is None:
+                            self.slots[slot] = req
+                        else:
+                            self.prefilling[slot] = (req, pend)
+                    self.admission_order.append(req)
+                    self._record_admission(req)
+                    self.stats.admitted += 1
+                    break
 
     def _advance_prefills(self) -> None:
         """Advance in-flight admissions FIFO, at most ``admission_budget``
@@ -349,8 +358,9 @@ class ContinuousScheduler:
                 if budget is not None and budget <= 0:
                     return
                 req, pend = self.prefilling[slot]
-                self._state, pend = self.backend.sched_admit_step(self._state,
-                                                                  pend)
+                with TraceAnnotation("serve.prefill_chunk", rid=req.rid):
+                    self._state, pend = self.backend.sched_admit_step(
+                        self._state, pend)
                 self.stats.prefill_chunks += 1
                 if budget is not None:
                     budget -= 1
@@ -365,39 +375,45 @@ class ContinuousScheduler:
         admission budget, run one decode step, deliver tokens.
 
         Returns the requests that finished this step (possibly empty)."""
-        if self._state is None:
-            self._state = self.backend.sched_start()
-        self._admit_free_slots()
-        self._advance_prefills()
-        if self.num_active == 0:
-            # pure-admission step: prefill chunks advanced, nothing to decode
-            # — still a step (it consumed wall-clock), tallied separately so
-            # decode throughput math stays honest
+        with TraceAnnotation("serve.step", live=self.num_active,
+                             queued=len(self.queue),
+                             prefilling=len(self.prefilling)):
+            if self._state is None:
+                self._state = self.backend.sched_start()
+            self._admit_free_slots()
+            self._advance_prefills()
+            if self.num_active == 0:
+                # pure-admission step: prefill chunks advanced, nothing to
+                # decode — still a step (it consumed wall-clock), tallied
+                # separately so decode throughput math stays honest
+                self.stats.steps += 1
+                self.stats.admission_steps += 1
+                return []
+            if getattr(self.backend, "spec_k", 0) >= 2 and \
+                    hasattr(self.backend, "sched_spec_step"):
+                return self._spec_step()
+            with TraceAnnotation("serve.decode"):
+                self._state, tokens, alive = self.backend.sched_step(
+                    self._state)
+            finished: list[Request] = []
+            with TraceAnnotation("serve.deliver"):
+                for slot, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    tok = int(tokens[slot])
+                    req.out.append(tok)
+                    self.stats.emitted_tokens += 1
+                    cb = req.on_token or self.on_token
+                    if cb is not None:
+                        cb(req, tok)
+                    if not bool(alive[slot]):
+                        req.done = True
+                        self.slots[slot] = None
+                        self.completed.append(req)
+                        self.stats.completed += 1
+                        finished.append(req)
             self.stats.steps += 1
-            self.stats.admission_steps += 1
-            return []
-        if getattr(self.backend, "spec_k", 0) >= 2 and \
-                hasattr(self.backend, "sched_spec_step"):
-            return self._spec_step()
-        self._state, tokens, alive = self.backend.sched_step(self._state)
-        finished: list[Request] = []
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(tokens[slot])
-            req.out.append(tok)
-            self.stats.emitted_tokens += 1
-            cb = req.on_token or self.on_token
-            if cb is not None:
-                cb(req, tok)
-            if not bool(alive[slot]):
-                req.done = True
-                self.slots[slot] = None
-                self.completed.append(req)
-                self.stats.completed += 1
-                finished.append(req)
-        self.stats.steps += 1
-        return finished
+            return finished
 
     def _spec_window(self, req: Request, K: int) -> int:
         """Next-round draft window for ``req`` from its acceptance EWMA:
@@ -420,43 +436,47 @@ class ContinuousScheduler:
         if self.dynamic_spec_k:
             window = [self._spec_window(req, K) if req is not None else K
                       for req in self.slots]
-            self._state, tokens, n_acc, n_emit, alive = \
-                self.backend.sched_spec_step(self._state, window)
         else:
             window = [K] * self.B
-            self._state, tokens, n_acc, n_emit, alive = \
-                self.backend.sched_spec_step(self._state)
+        with TraceAnnotation("serve.decode"):
+            if self.dynamic_spec_k:
+                self._state, tokens, n_acc, n_emit, alive = \
+                    self.backend.sched_spec_step(self._state, window)
+            else:
+                self._state, tokens, n_acc, n_emit, alive = \
+                    self.backend.sched_spec_step(self._state)
         self.stats.spec_rounds += 1
         finished: list[Request] = []
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            w = window[slot]
-            accepted = max(int(n_acc[slot]) - 1, 0)
-            self.stats.drafted_tokens += w - 1
-            self.stats.accepted_drafted_tokens += accepted
-            self.stats.accepted_by_rid[req.rid] = \
-                self.stats.accepted_by_rid.get(req.rid, 0) + accepted
-            if self.dynamic_spec_k:
-                self.stats.spec_window_by_rid[req.rid] = w
-                frac = accepted / (w - 1)
-                a = self.spec_acc_ewma
-                self._acc_ewma[req.rid] = \
-                    a * frac + (1.0 - a) * self._acc_ewma.get(req.rid, 1.0)
-            cb = req.on_token or self.on_token
-            for j in range(int(n_emit[slot])):
-                tok = int(tokens[slot, j])
-                req.out.append(tok)
-                self.stats.emitted_tokens += 1
-                if cb is not None:
-                    cb(req, tok)
-            if not bool(alive[slot]):
-                req.done = True
-                self.slots[slot] = None
-                self._acc_ewma.pop(req.rid, None)
-                self.completed.append(req)
-                self.stats.completed += 1
-                finished.append(req)
+        with TraceAnnotation("serve.deliver"):
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                w = window[slot]
+                accepted = max(int(n_acc[slot]) - 1, 0)
+                self.stats.drafted_tokens += w - 1
+                self.stats.accepted_drafted_tokens += accepted
+                self.stats.accepted_by_rid[req.rid] = \
+                    self.stats.accepted_by_rid.get(req.rid, 0) + accepted
+                if self.dynamic_spec_k:
+                    self.stats.spec_window_by_rid[req.rid] = w
+                    frac = accepted / (w - 1)
+                    a = self.spec_acc_ewma
+                    self._acc_ewma[req.rid] = a * frac + \
+                        (1.0 - a) * self._acc_ewma.get(req.rid, 1.0)
+                cb = req.on_token or self.on_token
+                for j in range(int(n_emit[slot])):
+                    tok = int(tokens[slot, j])
+                    req.out.append(tok)
+                    self.stats.emitted_tokens += 1
+                    if cb is not None:
+                        cb(req, tok)
+                if not bool(alive[slot]):
+                    req.done = True
+                    self.slots[slot] = None
+                    self._acc_ewma.pop(req.rid, None)
+                    self.completed.append(req)
+                    self.stats.completed += 1
+                    finished.append(req)
         self.stats.steps += 1
         return finished
 

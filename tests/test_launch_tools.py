@@ -1,13 +1,9 @@
-"""Launch-layer tooling: report rendering, profiler, roofline math,
+"""Launch-layer tooling: report rendering, roofline math,
 collective parsing edge cases."""
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.launch import hlo_cost, mesh as mesh_mod, roofline as rl
-from repro.launch.profile import profile_text
 from repro.launch.report import dryrun_table, roofline_table, summary
 
 
@@ -55,18 +51,6 @@ def test_report_tables_render():
     t2 = dryrun_table(recs)
     assert "2x16x16" in t2
     assert "cells compiled" in summary(recs)
-
-
-def test_profile_text_on_tiny_program():
-    def f(x, w):
-        def body(c, wi):
-            return jnp.tanh(c @ wi), None
-        return jax.lax.scan(body, x, w)[0]
-
-    c = jax.jit(f).lower(jax.ShapeDtypeStruct((32, 64), jnp.float32),
-                         jax.ShapeDtypeStruct((4, 64, 64), jnp.float32)).compile()
-    out = profile_text(c.as_text(), top=5)
-    assert "total:" in out and "GFLOP" in out
 
 
 def test_collective_parser_shapes():
