@@ -210,8 +210,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     late = win.lateness
     say(f"window {win.seconds:.1f} s: {len(win.due_in_window())} due, "
         f"{len(win.completed_in_window())} completed, "
-        f"{win.tokens_in_window} tokens, {win.decode_steps_in_window} decode "
-        f"steps, queued {win.queued_at_open} -> {win.queued_at_close}, "
+        f"{win.served_tokens()} served tokens of those due, "
+        f"{win.tokens_in_window} tokens visible in all, "
+        f"{win.decode_steps_in_window} decode steps, "
+        f"queued {win.queued_at_open} -> {win.queued_at_close}, "
         f"generator late max {max(late) if late else 0.0:.4f} s")
     for name, c in checks.items():
         say(f"check {name} = {c['value']} limit {c['limit']}")
@@ -223,7 +225,7 @@ def end_to_end(cell: Cell, win: window.Window, setup_s: float) -> dict:
     """The cell's end-to-end metrics, each taken over the whole window."""
     from bench.stats import percentile
 
-    values = {"output_tok_s": (win.output_tok_s(), "tokens/s"),
+    values = {"served_tok_s": (win.served_tok_s(), "tokens/s"),
               "setup_s": (setup_s, "s")}
     if win.due_in_window():
         values["ttft_p90_s"] = (percentile(win.ttfts(), 90), "s")

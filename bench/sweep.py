@@ -79,7 +79,7 @@ def main(argv=None) -> int:
                    "attainment": attainment(win, mix["limits"]),
                    "ttft_p90_s": percentile(ttfts, 90) if ttfts else None,
                    "tpot_p90_s": percentile(tpots, 90) if tpots else None,
-                   "output_tok_s": win.output_tok_s(),
+                   "served_tok_s": win.served_tok_s(),
                    "queued_open": win.queued_at_open,
                    "queued_close": win.queued_at_close}
             print(json.dumps(row), flush=True)

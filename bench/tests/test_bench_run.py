@@ -71,7 +71,7 @@ def test_the_sound_program_is_correct(root, tmp_path):
                              "device"]
     assert list(out)[-1] == "checks"
     assert out["correct"], out["checks"]
-    assert set(out["metrics"]) == {"output_tok_s", "ttft_p90_s",
+    assert set(out["metrics"]) == {"served_tok_s", "ttft_p90_s",
                                    "tpot_p90_s", "setup_s"}
     assert all(v["value"] > 0 for v in out["metrics"].values())
     assert out["attempted"] > 0 and out["failed"] == 0

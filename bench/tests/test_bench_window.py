@@ -120,15 +120,55 @@ def test_request_in_flight_at_the_close_counts_its_wait_so_far():
     assert len(w.ttfts()) == len(w.due_in_window())
 
 
+def tokens_visible_in_window(w):
+    """The count the benchmark used before ``served_tok_s``: every token
+    visible inside the window, whenever its request was due."""
+    return sum(1 for r in w.records for t in r.times if w.open <= t < w.close)
+
+
 @pytest.mark.parametrize("mix", [CHAT, DOCS], ids=["open", "closed"])
-def test_output_tok_s_counts_only_tokens_inside_the_window(mix):
+def test_served_tok_s_counts_only_tokens_of_requests_due_in_the_window(mix):
     w, _ = drive(mix)
-    inside = sum(1 for r in w.records for t in r.times
-                 if w.open <= t < w.close)
-    total = sum(len(r.times) for r in w.records)
-    assert inside < total
-    assert w.tokens_in_window == inside
-    assert w.output_tok_s() == pytest.approx(inside / 5.0)
+    due = [r for r in w.records if w.open <= r.due < w.close]
+    served = sum(1 for r in due for t in r.times if t < w.close)
+    # both kinds of token the count leaves out occur in this drive: those of
+    # requests due in warm-up that became visible inside the window, and
+    # those of requests due in the window that became visible at its close
+    # or after
+    assert any(w.open <= t < w.close for r in w.records if r.due < w.open
+               for t in r.times)
+    assert any(t >= w.close for r in due for t in r.times)
+    assert w.due_in_window() == due
+    assert 0 < w.served_tokens() == served < sum(len(r.times) for r in due)
+    assert w.served_tok_s() == pytest.approx(served / 5.0)
+    assert w.tokens_in_window == tokens_visible_in_window(w)
+
+
+#: open loop whose warm-up requests are still decoding when the window opens
+#: on a backend of 0.05 s a step, which finishes the window's requests well
+#: before the close (as the chat cell does on the chip)
+EDGE = {"loop": "open", "arrivals": {"kind": "poisson", "rate_per_s": 0.5},
+        "prompt_tokens": {"kind": "uniform", "min": 8, "max": 16},
+        "output_tokens": {"kind": "uniform", "min": 60, "max": 120},
+        "slots": 4, "max_len": 256, "warmup_s": 4.0}
+
+
+def test_a_faster_backend_never_serves_fewer_tokens():
+    base, _ = drive(EDGE, seconds=20.0, step_s=0.05)
+    assert any(r.due < base.open <= r.last for r in base.records), \
+        "a warm-up request decodes into the window"
+    due = base.due_in_window()
+    assert base.served_tokens() == sum(r.budget for r in due)
+
+    fast, _ = drive(EDGE, seconds=20.0, step_s=0.025)
+    # the old count read the faster backend as the slower one: it finished
+    # more of the warm-up's tokens before the window opened
+    assert tokens_visible_in_window(fast) < tokens_visible_in_window(base)
+    assert fast.served_tok_s() >= base.served_tok_s()
+
+    slow, _ = drive(EDGE, seconds=20.0, step_s=0.1)
+    assert slow.served_tokens() < sum(r.budget for r in slow.due_in_window())
+    assert slow.served_tok_s() < base.served_tok_s()
 
 
 def test_tpot_is_of_requests_completed_inside_the_window():

@@ -10,7 +10,10 @@ Times are seconds after the traffic starts.  A token becomes visible at the
 end of the scheduler step that emitted it.  The window is
 ``[warmup_s, warmup_s + seconds)``:
 
-- ``output_tok_s``: tokens visible inside the window, over ``seconds``;
+- ``served_tok_s``: output tokens of the requests due in the window that
+  became visible before its close, over ``seconds`` (tokens of requests due
+  in warm-up do not count, nor does a token visible at or after the close;
+  in a closed loop a request is due when it is submitted);
 - TTFT of a request due in the window is timed from its due time; one with
   no token by the close counts with its wait so far;
 - TPOT is ``(last - first) / (tokens - 1)`` of each request that completed
@@ -118,10 +121,9 @@ class Window:
     close: float
     records: list[Record]
     spans: list[Span]
+    #: tokens of any request that became visible inside the window
     tokens_in_window: int = 0
     decode_steps_in_window: int = 0
-    #: tokens emitted by decode steps that ended inside the window
-    step_tokens_in_window: int = 0
     compiles_in_window: int = 0
     #: (submit - due) of each request submitted inside the window
     lateness: list[float] = field(default_factory=list)
@@ -148,8 +150,14 @@ class Window:
         return [r for r in self.records
                 if r.done is not None and self.open <= r.done < self.close]
 
-    def output_tok_s(self) -> float:
-        return self.tokens_in_window / self.seconds
+    def served_tokens(self) -> int:
+        """Output tokens of the requests due in the window that became
+        visible before its close."""
+        return sum(1 for r in self.due_in_window() for t in r.times
+                   if t < self.close)
+
+    def served_tok_s(self) -> float:
+        return self.served_tokens() / self.seconds
 
     def ttfts(self) -> list[float]:
         return [r.ttft(self.close) for r in self.due_in_window()]
@@ -277,7 +285,6 @@ def drive(backend, mix: dict, seed: int, vocab: int, *, seconds: float,
             win.tokens_in_window += len(buffer)
             if sched.stats.decode_steps > decode_steps0:
                 win.decode_steps_in_window += 1
-                win.step_tokens_in_window += len(buffer)
         buffer.clear()
         for req in finished:
             records[req.rid].done = t
