@@ -20,6 +20,12 @@ lets Mosaic compile the kernel at real model widths.
 
 Float activations accumulate in f32; int8 activations accumulate exactly in
 int32 on the MXU's integer path (:mod:`repro.kernels.w2a8_matmul`).
+
+The grid is ``(row blocks, O/bo, NB/bnb)``, row blocks outermost, so every
+row block streams the whole packed matrix from HBM and expands it again.  A
+call of up to :data:`ROW_BLOCK_CAP` rows — a decode step over the slots, a
+prefill chunk — is therefore one row block (:func:`row_blocks`): each packed
+tile is DMA'd and expanded once per call, whatever the call's row count.
 """
 
 from __future__ import annotations
@@ -78,6 +84,29 @@ def _dense_kernel(x_ref, p_ref, out_ref):
                                  out_ref.dtype)
 
 
+#: Most rows in one row block.  VMEM one grid step holds at 256 rows,
+#: ``block_o`` 128 and ``bnb`` 128 bytes, inputs and output double-buffered:
+#: activation planes 2 x 5 x 256 x 128 x (1 B int8 | 2 B bf16 | 4 B f32) =
+#: 320 KiB | 640 KiB | 1.25 MiB; packed tile 2 x 128 x 128 B = 32 KiB;
+#: output tile 2 x 256 x 128 x 4 B = 256 KiB; int32 digit tile and trit tile
+#: 2 x 128 x 128 x 4 B = 128 KiB; one plane's product 256 x 128 x 4 B =
+#: 128 KiB.  At most ~1.8 MiB (f32), far inside v5e's 16 MiB default scoped
+#: VMEM; ``tests/test_tpu_compile.py`` compiles a block of this size.
+ROW_BLOCK_CAP = 256
+
+
+def row_blocks(rows: int, block_b: int | None = None) -> tuple[int, int]:
+    """``(rows per block, number of row blocks)`` for a call of ``rows``.
+
+    ``block_b=None`` takes every row into one block up to
+    :data:`ROW_BLOCK_CAP` (a block equal to the full row dimension is always
+    legal for Mosaic, so no alignment padding) and tiles at the cap above
+    it; an explicit ``block_b`` tiles at ``min(block_b, rows)``.  Rows are
+    padded to ``block * count``."""
+    block = min(rows, ROW_BLOCK_CAP if block_b is None else block_b)
+    return block, -(-rows // block)
+
+
 def block_bytes(block_n: int, nb: int) -> int:
     """Packed bytes per reduction step: ``block_n`` trits, capped at the
     row's ``nb`` bytes (a block equal to the full dim is always legal)."""
@@ -90,16 +119,19 @@ def packed_call(x, packed, n, *, block_b, block_o, block_n, interpret,
 
     x: [B, N] activations; packed: [O, NB] base-3 bytes (NB may carry
     alignment padding past ``ceil(n/5)``).  Returns [B, O] ``acc_dtype``.
-    Pad *bytes* decode to -1 trits but meet zero activations, so their
-    products vanish; padded B/O rows are sliced off after the call."""
+    Rows are tiled by :func:`row_blocks` (``block_b=None``: one block for
+    up to :data:`ROW_BLOCK_CAP` rows).  Pad *bytes* decode to -1 trits but
+    meet zero activations, so their products vanish; padded B/O rows are
+    sliced off after the call."""
     B, N = x.shape
     O, NB = packed.shape
     if N < n or NB * TRITS_PER_BYTE < n:
         raise ValueError((N, NB, n))
     bnb = block_bytes(block_n, NB)
-    block_b = min(block_b, B)
+    block_b, n_row_blocks = row_blocks(B, block_b)
     block_o = min(block_o, O)
-    pad_b, pad_o, pad_nb = (-B) % block_b, (-O) % block_o, (-NB) % bnb
+    pad_b = block_b * n_row_blocks - B
+    pad_o, pad_nb = (-O) % block_o, (-NB) % bnb
     planes = digit_planes(x, NB)                       # [5, B, NB]
     if pad_b or pad_nb:
         planes = jnp.pad(planes, ((0, 0), (0, pad_b), (0, pad_nb)))
@@ -130,12 +162,16 @@ def packed_matmul(
     packed: jax.Array,
     n: int,
     *,
-    block_b: int = 8,
+    block_b: int | None = None,
     block_o: int = 128,
     block_n: int = 640,  # 128 packed bytes: one lane-width of each plane
     interpret: bool | None = None,
 ) -> jax.Array:
     """y[b, o] = Σ_n x[b, n] · unpack(packed)[o, n], accumulated in f32.
+
+    Rows run as one block up to :data:`ROW_BLOCK_CAP` (``block_b=None``),
+    so each packed tile is streamed and expanded once per call; an explicit
+    ``block_b`` tiles rows at that size.
 
     Args:
       x:      [B, N] float activations (N may include padding up to 5·packed

@@ -33,7 +33,7 @@ def w2a8_matmul(
     packed: jax.Array,
     n: int,
     *,
-    block_b: int = 8,
+    block_b: int | None = None,
     block_o: int = 128,
     block_n: int = 640,
     interpret: bool | None = None,
@@ -44,7 +44,11 @@ def w2a8_matmul(
     packed: [O, ceil(N/5)] base-3 ternary weights.
 
     The packed-streaming kernel of :mod:`repro.kernels.dequant_matmul` with
-    trits expanded to int8 and an int32 accumulator.
+    trits expanded to int8 and an int32 accumulator.  Rows run as one block
+    up to ``ROW_BLOCK_CAP`` (``block_b=None``), so a prefill chunk streams
+    and expands each packed tile once, as a decode step does; an explicit
+    ``block_b`` tiles rows at that size.  Rows never meet in the exact int32
+    contraction, so the row block does not change a single output bit.
     """
     if x_q.dtype != jnp.int8:
         raise TypeError(f"w2a8_matmul needs int8 activations, got {x_q.dtype}")

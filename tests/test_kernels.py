@@ -52,6 +52,15 @@ def test_packed_kernel(B, O, N):
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-4)
 
 
+def test_packed_kernel_prefill_chunk_one_row_block():
+    """A 32-row prefill chunk at the default (single) row block."""
+    x, w = _data(4, 32, 136, 700)
+    p = encoding.pack_base3(w)
+    y = packed_matmul(x, p, 700)
+    y_ref = ref.packed_matmul_ref(x, p, 700)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-4)
+
+
 @pytest.mark.parametrize("mu", [1, 2, 3, 4, 5])
 @pytest.mark.parametrize("fetch", ["onehot", "gather"])
 def test_lut_kernel_mu_sweep(mu, fetch):

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
 from repro.kernels import dispatch as dp
+from repro.kernels.dequant_matmul import ROW_BLOCK_CAP, row_blocks
 from repro.models.decode import layer_matmul_problems
 
 CFG = get_config("bitnet-b1.58-2b")
@@ -82,6 +83,16 @@ def test_selected_kernel_compiles(one_chip, act, m, k, n):
                             cache=EMPTY_CACHE)
     assert spec.name == {"bfloat16": "dequant_packed", "int8": "w2a8"}[act]
     text = _compile(spec, one_chip, m, k, n, act)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("act", DTYPES)
+def test_largest_row_block_compiles(one_chip, act):
+    """A single row block of ``ROW_BLOCK_CAP`` rows fits VMEM under Mosaic,
+    on the widest reduction (the down projection, K = d_ff)."""
+    spec = dp.REGISTRY[{"bfloat16": "dequant_packed", "int8": "w2a8"}[act]]
+    assert row_blocks(ROW_BLOCK_CAP) == (ROW_BLOCK_CAP, 1)
+    text = _compile(spec, one_chip, ROW_BLOCK_CAP, CFG.d_ff, CFG.d_model, act)
     assert "tpu_custom_call" in text
 
 
