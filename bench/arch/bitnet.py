@@ -1,8 +1,9 @@
-"""Plain float32 reference of the served model, built from the seed alone.
+"""bitnet-b1.58: its plain float32 reference, the keys the program is held
+to, and its work counts.
 
-It imports nothing of the program under test.  It reads the sizes from the
-configuration file's keys and draws the same master weights from
-``jax.random.PRNGKey(seed)`` in the layout the served checkpoint uses:
+The reference imports nothing of the program under test.  It reads the
+sizes from the configuration file's keys and draws the same master weights
+from ``jax.random.PRNGKey(seed)`` in the layout the served checkpoint uses:
 
 - ``split(key, 16)``: 0 embedding ``[V, d]`` (normal * 0.02), 1 LM head
   ``[d, V]`` (normal / sqrt d; absent when tied), then the stacked attention
@@ -19,6 +20,10 @@ rotary embedding (halves rotated), causal grouped-query attention, gated
 FFN, untied or tied LM head.  With ``act_bits`` set, every ternary
 projection first quantizes its input per token to signed ``act_bits``
 integers by absmax (BitNet b1.58's W1.58A8 at 8 bits).
+
+The work counts take every layer as seven dense ternary projections (q, k,
+v, o, gate, up, down) and attention as ``QK^T`` plus ``PV`` over the live
+context, on the shared rules of ``bench/work.py``.
 """
 
 from __future__ import annotations
@@ -30,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import work
+
 HI = jax.lax.Precision.HIGHEST
 EPS_SCALE = 1e-6
 #: ``score`` pads the sequence and the rows it reads to multiples of these,
@@ -38,6 +45,16 @@ EPS_SCALE = 1e-6
 SEQ_BLOCK = 2048
 ROW_BLOCK = 1024
 ACTS = {"silu": jax.nn.silu}
+
+#: configuration file key -> the program's ``ModelConfig`` attribute, each
+#: held equal by ``harness.program_config``
+PROGRAM_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
+                "num_hidden_layers": "n_layers",
+                "num_attention_heads": "n_heads",
+                "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+                "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+                "hidden_act": "act_fn", "tie_word_embeddings": "tie_embeddings",
+                "torch_dtype": "dtype"}
 
 
 def sizes(model: dict) -> dict:
@@ -182,3 +199,45 @@ def _score(key_sizes, act_bits, w, tokens, rows, targets):
     gap = jnp.max(logits, axis=-1) - jnp.take_along_axis(
         logits, targets[:, None], axis=-1)[:, 0]
     return gap, best
+
+
+def projections(model: dict) -> list[tuple[str, int, int]]:
+    """``(role, K, N)`` of each ternary projection of one layer."""
+    d = model["hidden_size"]
+    h = model["num_attention_heads"]
+    hd = model.get("head_dim") or d // h
+    kv = model["num_key_value_heads"] * hd
+    ff = model["intermediate_size"]
+    return [("q", d, h * hd), ("k", d, kv), ("v", d, kv), ("o", h * hd, d),
+            ("gate", d, ff), ("up", d, ff), ("down", ff, d)]
+
+
+def least_seconds(m: int, model: dict, act_bytes: int, peak_flops: float,
+                  hbm: float) -> float:
+    """Least time for every ternary projection of one forward over ``m``
+    rows: per projection the larger of its compute and its memory bound."""
+    per_layer = 0.0
+    for _, k, n in projections(model):
+        flops, nbytes = work.projection_work(m, k, n, act_bytes)
+        per_layer += max(flops / peak_flops, nbytes / hbm)
+    return per_layer * model["num_hidden_layers"]
+
+
+def ternary_params(model: dict) -> int:
+    return model["num_hidden_layers"] * sum(k * n for _, k, n in
+                                            projections(model))
+
+
+def attention_flops(model: dict, context: int) -> float:
+    """``QK^T`` and ``PV`` of one token over ``context`` positions."""
+    d = model["hidden_size"]
+    h = model["num_attention_heads"]
+    hd = model.get("head_dim") or d // h
+    return 4.0 * model["num_hidden_layers"] * h * hd * context
+
+
+def token_flops(model: dict, context: int, head: bool) -> float:
+    """Model FLOPs of one token at ``context`` positions (itself included),
+    with the LM head where that token's logits are needed."""
+    return 2.0 * ternary_params(model) + attention_flops(model, context) + \
+        (work.head_flops(model) if head else 0.0)

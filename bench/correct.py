@@ -2,8 +2,9 @@
 
 After the window closes and the program's state is freed, a sample of the
 requests the timed path finished, drawn from the seed with the longest one
-in it, is run through the float32 reference (``bench/reference.py``) over
-each prompt followed by its served tokens.  The number compared is the
+in it, is run through the float32 reference that the configuration's
+architecture module gives (``bench/arch/<architecture>.py``) over each
+prompt followed by its served tokens.  The number compared is the
 widest gap by which a served token's logit lies below the reference's best
 at that position (greedy serving: a correct server's gap is rounding only).
 Every finished request must also have been served exactly its token budget
@@ -17,7 +18,7 @@ the readings each limit was set from.
 
 from __future__ import annotations
 
-from bench import traffic
+from bench import arch, traffic
 
 #: served tokens the sample reaches before it stops adding requests
 SAMPLE_TOKENS = 512
@@ -60,8 +61,7 @@ def verify(win, model: dict, seed: int, limits: dict) -> dict:
     missing limit is ``None`` and fails."""
     import jax
 
-    from bench.reference import Reference
-
+    Reference = arch.load(model).Reference
     bits = 8 if model["activations"].startswith("int8") else None
     finished = [r for r in win.records if r.done is not None]
     wrong = sum(1 for r in finished

@@ -17,7 +17,7 @@ import time
 from dataclasses import dataclass
 from pathlib import Path
 
-from bench import correct, traffic, window
+from bench import arch, correct, traffic, window
 from bench import trace as trace_mod
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -49,7 +49,9 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"no workload {workload!r}; known: {sorted(specs)}")
     spec = specs[workload]
     cfgs = {c["name"]: c for c in bench["configs"]}
-    model = json.loads((root / cfgs[spec["config"]]["file"]).read_text())
+    cfg_file = cfgs[spec["config"]]["file"]
+    model = json.loads((root / cfg_file).read_text())
+    arch.load(model, cfg_file)
     mix = traffic.load(root / "bench" / "traffic" / f"{spec['traffic']}.json")
     lim = root / "bench" / "limits" / f"{workload}.json"
     limits = json.loads(lim.read_text()) if lim.exists() else {}
@@ -72,19 +74,14 @@ def check_devices(chips: int, require_tpu: bool = True) -> dict:
 
 def program_config(model: dict):
     """The program's configuration for a configuration file, checked key by
-    key against the file."""
+    key against the file: every key of its architecture module's
+    ``PROGRAM_KEYS``."""
     from repro.configs.registry import get_config
 
     srv = model["serving"]
     cfg = get_config(srv["arch"]).with_(act_dtype=srv["act_dtype"])
-    want = {"d_model": model["hidden_size"], "d_ff": model["intermediate_size"],
-            "n_layers": model["num_hidden_layers"],
-            "n_heads": model["num_attention_heads"],
-            "n_kv_heads": model["num_key_value_heads"],
-            "head_dim": model["head_dim"], "vocab_size": model["vocab_size"],
-            "rope_theta": model["rope_theta"], "act_fn": model["hidden_act"],
-            "tie_embeddings": model["tie_word_embeddings"],
-            "dtype": model["torch_dtype"]}
+    want = {attr: model[key]
+            for key, attr in arch.load(model).PROGRAM_KEYS.items()}
     bad = {k: (getattr(cfg, k), v) for k, v in want.items()
            if getattr(cfg, k) != v}
     if bad:

@@ -6,7 +6,8 @@
 In one process, for each seed of ``--seeds`` it makes the served model,
 drives the cell's traffic for its warm-up and a window of ``--seconds``,
 frees the program and reads the widest logit gap of the sampled served
-tokens under the reference (``bench/correct.py``): the sound program's
+tokens under the configuration's reference (``bench/correct.py``, with the
+``Reference`` of ``bench/arch/<architecture>.py``): the sound program's
 readings, whose largest is the limit's lower reading.
 
 The control is the reference's precision one step below the one the
@@ -38,11 +39,10 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 def gaps_of(win, model: dict, seed: int) -> list[float]:
     import jax
 
-    from bench import correct
-    from bench.reference import Reference
+    from bench import arch, correct
 
     with jax.default_matmul_precision("highest"):
-        ref = Reference(model, seed)
+        ref = arch.load(model).Reference(model, seed)
         return correct.served_gaps(ref, correct.sample(win.records, seed),
                                    8 if model["activations"].startswith(
                                        "int8") else None)
@@ -64,12 +64,11 @@ def int4_control(win, model: dict, seed: int) -> list[float]:
     over the sample of ``win``."""
     import jax
 
-    from bench import correct
-    from bench.reference import Reference
+    from bench import arch, correct
 
     gaps = []
     with jax.default_matmul_precision("highest"):
-        ref = Reference(model, seed)
+        ref = arch.load(model).Reference(model, seed)
         for r in correct.sample(win.records, seed):
             toks = r.prompt + r.request.out[:-1]
             p = len(r.prompt)

@@ -2,8 +2,9 @@
 over the device time of the ops that ran them.
 
 The work is each step's projections at the model's shapes (decode steps at
-M = slots, prefill chunks at M = chunk), counted by ``bench/work.py`` at
-1.6 bits per weight; the least time of a projection is the larger of its
+M = slots, prefill chunks at M = chunk), counted by the configuration's
+architecture module (``least_seconds`` of ``bench/arch/<architecture>.py``)
+at 1.6 bits per weight; the least time of a projection is the larger of its
 operations over the peak and its bytes over HBM bandwidth.  The ops are
 matched by the name patterns of ``bench/kernels/<kernel>.json`` for each
 kernel dispatch chose (``DecodeEngine.kernel_choices``).  A chosen kernel
@@ -14,7 +15,7 @@ unseen."""
 import json
 from pathlib import Path
 
-from bench import work
+from bench import arch, work
 
 KERNELS = Path(__file__).resolve().parents[1] / "kernels"
 
@@ -38,12 +39,13 @@ def read(ctx):
         raise ValueError(f"no op of the trace matches {patterns}, though "
                          f"{steps} steps and {chunks} chunks ran")
     model = ctx.cell.model
+    least_seconds = arch.load(model).least_seconds
     int8 = model["activations"].startswith("int8")
     pk = work.peaks(ctx.device_kind)
     peak = pk["int8_ops"] if int8 else pk["bf16_flops"]
     act_bytes = 1 if int8 else 2
-    least = steps * work.least_seconds(ctx.win.slots, model, act_bytes, peak,
-                                       pk["hbm_bytes_per_s"]) \
-        + chunks * work.least_seconds(ctx.prefill_chunk, model, act_bytes,
-                                      peak, pk["hbm_bytes_per_s"])
+    least = steps * least_seconds(ctx.win.slots, model, act_bytes, peak,
+                                  pk["hbm_bytes_per_s"]) \
+        + chunks * least_seconds(ctx.prefill_chunk, model, act_bytes, peak,
+                                 pk["hbm_bytes_per_s"])
     return 100.0 * least / device_s

@@ -14,7 +14,7 @@ import pytest  # noqa: E402
 def small_reference_blocks(monkeypatch):
     """The tests' sequences are short: pad the reference's inputs to small
     blocks, not to the chat mix's 2048 positions."""
-    from bench import reference
+    from bench.arch import bitnet
 
-    monkeypatch.setattr(reference, "SEQ_BLOCK", 512)
-    monkeypatch.setattr(reference, "ROW_BLOCK", 256)
+    monkeypatch.setattr(bitnet, "SEQ_BLOCK", 512)
+    monkeypatch.setattr(bitnet, "ROW_BLOCK", 256)

@@ -1,9 +1,14 @@
-"""The comparison that decides ``correct`` (``correct.judge``) and the
-roofline reader's refusal of a kernel name table that matches nothing."""
+"""The comparison that decides ``correct`` (``correct.judge``), the
+reference it runs (the configuration's), and the roofline reader's refusal
+of a kernel name table that matches nothing."""
 
 import importlib.util
+import sys
+import types
 from pathlib import Path
 from types import SimpleNamespace
+
+import numpy as np
 
 import pytest
 
@@ -39,6 +44,39 @@ def test_each_check_holds_its_limit(gaps, wrong, ok):
     assert correct.passed(correct.judge(gaps, wrong, LIMITS)) is ok
 
 
+def _stub_arch(monkeypatch, gap):
+    """An architecture module ``bench.arch.stub`` whose reference reads
+    ``gap`` at every position."""
+    class Reference:
+        def __init__(self, model, seed):
+            pass
+
+        def score(self, tokens, rows, targets, act_bits):
+            return np.full(len(rows), gap), np.zeros(len(rows), np.int32)
+
+    mod = types.ModuleType("bench.arch.stub")
+    mod.Reference = Reference
+    monkeypatch.setitem(sys.modules, "bench.arch.stub", mod)
+
+
+def _finished(rid, prompt, out):
+    req = SimpleNamespace(out=out)
+    return SimpleNamespace(rid=rid, prompt=prompt, request=req, done=1.0,
+                           n_out=len(out), budget=len(out))
+
+
+@pytest.mark.parametrize("gap,ok", [(0.0, True), (5.0, False)])
+def test_verify_runs_the_reference_the_configuration_names(monkeypatch, gap,
+                                                            ok):
+    _stub_arch(monkeypatch, gap)
+    win = SimpleNamespace(records=[_finished(0, [5, 6, 7], [8, 9]),
+                                   _finished(1, [4], [3, 2, 1])])
+    model = {"architecture": "stub", "activations": "bfloat16"}
+    checks = correct.verify(win, model, 7, LIMITS)
+    assert checks["max_logit_gap"]["value"] == gap
+    assert correct.passed(checks) is ok
+
+
 def _roofline():
     spec = importlib.util.spec_from_file_location(
         "bench_metric_ternary_roofline", BENCH / "metrics" /
@@ -51,7 +89,7 @@ def _roofline():
 def _ctx(kernels, op="packed_matmul.3"):
     summary = trace_mod.Summary(ops=[(op, 0.1, 0.05)], busy_s=0.05,
                                 window_s=1.0, t_start=0.0, t_stop=1.0)
-    model = {"hidden_size": 256, "intermediate_size": 512,
+    model = {"architecture": "bitnet", "hidden_size": 256, "intermediate_size": 512,
              "num_hidden_layers": 2, "num_attention_heads": 4,
              "num_key_value_heads": 2, "head_dim": 64,
              "activations": "bfloat16"}

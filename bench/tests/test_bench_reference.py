@@ -10,7 +10,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench.reference import Reference
+from bench import arch
+from bench.arch.bitnet import Reference
 
 MODEL = {"hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 4,
          "num_attention_heads": 4, "num_key_value_heads": 1, "head_dim": 32,
@@ -65,15 +66,26 @@ def widest(ref, reqs, bits, argmax_of=None):
     return out
 
 
-def test_the_reference_imports_nothing_of_the_program():
-    src = (Path(__file__).resolve().parents[1] / "reference.py").read_text()
-    mods = set()
+#: what a reference may import: the standard library's few, jax and numpy,
+#: and of the benchmark only its shared work rules
+ALLOWED = {"__future__", "functools", "math", "jax", "numpy", "bench.work"}
+
+
+def imported(src: str):
+    """Each name that ``src`` imports, with its module's dotted path."""
     for node in ast.walk(ast.parse(src)):
         if isinstance(node, ast.Import):
-            mods |= {a.name.split(".")[0] for a in node.names}
+            yield from (a.name for a in node.names)
         elif isinstance(node, ast.ImportFrom):
-            mods.add((node.module or "").split(".")[0])
-    assert mods <= {"__future__", "functools", "math", "jax", "numpy"}
+            yield from (f"{node.module}.{a.name}" for a in node.names)
+
+
+@pytest.mark.parametrize("name", arch.names())
+def test_the_reference_imports_nothing_of_the_program(name):
+    src = (Path(arch.__file__).parent / f"{name}.py").read_text()
+    bad = [m for m in imported(src)
+           if not any(m == a or m.startswith(a + ".") for a in ALLOWED)]
+    assert not bad
 
 
 @pytest.mark.parametrize("seed", [0, 2**31 + 77])

@@ -10,7 +10,7 @@ import pytest
 from bench import harness
 
 TINY = {
-    "name": "tiny", "hidden_size": 128, "intermediate_size": 256,
+    "name": "tiny", "architecture": "bitnet", "hidden_size": 128, "intermediate_size": 256,
     "num_hidden_layers": 4, "num_attention_heads": 4,
     "num_key_value_heads": 1, "head_dim": 32, "vocab_size": 512,
     "hidden_act": "silu", "rope_theta": 10000.0, "rms_norm_eps": 1e-06,

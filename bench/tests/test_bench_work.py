@@ -1,4 +1,6 @@
-"""Work counts and the peaks table, against values worked out by hand."""
+"""Work counts and the peaks table, against values worked out by hand: the
+shared rules of ``bench/work.py`` and bitnet's counts in
+``bench/arch/bitnet.py``."""
 
 import json
 from pathlib import Path
@@ -6,6 +8,7 @@ from pathlib import Path
 import pytest
 
 from bench import work
+from bench.arch import bitnet
 
 ROOT = Path(__file__).resolve().parents[2]
 MODEL = json.loads((ROOT / "bench" / "configs" / "bitnet2b-bf16.json")
@@ -19,7 +22,7 @@ SHAPES = [("q", 2560, 2560), ("k", 2560, 640), ("v", 2560, 640),
 
 
 def test_projection_shapes():
-    assert work.projections(MODEL) == SHAPES
+    assert bitnet.projections(MODEL) == SHAPES
 
 
 @pytest.mark.parametrize("m,k,n,act,flops,nbytes", [
@@ -41,14 +44,14 @@ def test_projection_work(m, k, n, act, flops, nbytes):
 def test_model_flops_per_token():
     # ternary parameters per layer: 2*2560^2 + 2*2560*640 + 3*2560*6912
     per_layer = 13_107_200 + 3_276_800 + 53_084_160
-    assert work.ternary_params(MODEL) == 30 * per_layer == 2_084_044_800
+    assert bitnet.ternary_params(MODEL) == 30 * per_layer == 2_084_044_800
     # LM head: 2 * 2560 * 128256
     assert work.head_flops(MODEL) == 656_670_720
     # attention at 1000 positions: 4 * 30 layers * 20 heads * 128 * 1000
-    assert work.attention_flops(MODEL, 1000) == 307_200_000
-    assert work.token_flops(MODEL, 1000, head=True) == \
+    assert bitnet.attention_flops(MODEL, 1000) == 307_200_000
+    assert bitnet.token_flops(MODEL, 1000, head=True) == \
         2 * 2_084_044_800 + 307_200_000 + 656_670_720
-    assert work.token_flops(MODEL, 1, head=False) == \
+    assert bitnet.token_flops(MODEL, 1, head=False) == \
         2 * 2_084_044_800 + 4 * 30 * 20 * 128
 
 
@@ -59,8 +62,8 @@ def test_least_seconds_takes_the_larger_bound_per_projection():
         f = 2 * 8 * k * n
         b = k * n / 5 + 8 * k * 2 + 8 * n * 2
         want += max(f / 197e12, b / 819e9)
-    got = work.least_seconds(8, MODEL, 2, pk["bf16_flops"],
-                             pk["hbm_bytes_per_s"])
+    got = bitnet.least_seconds(8, MODEL, 2, pk["bf16_flops"],
+                               pk["hbm_bytes_per_s"])
     assert got == pytest.approx(30 * want, rel=1e-12)
 
 

@@ -1,10 +1,11 @@
-"""Operations and bytes the model's work needs, from its shapes alone.
+"""The shared rules by which the work counts of ``bench/arch/<name>.py``
+are made, and the chip's peaks.
 
-The ternary projections are counted at 1.6 bits per weight (five trits to a
-byte), whatever kernel or packing runs them; activations come in at their
+A ternary projection is counted at 1.6 bits per weight (five trits to a
+byte), whatever kernel or packing runs it; activations come in at their
 served width (2 bytes, or 1 under int8 activations) and outputs leave in
-bf16.  Model FLOPs count 2 per parameter touched per token, and attention
-as ``QK^T`` plus ``PV`` over the live context.
+bf16.  Model FLOPs count 2 per parameter touched per token; the LM head is
+``2 * hidden_size * vocab_size`` of them.
 """
 
 from __future__ import annotations
@@ -27,17 +28,6 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def projections(model: dict) -> list[tuple[str, int, int]]:
-    """``(role, K, N)`` of each ternary projection of one layer."""
-    d = model["hidden_size"]
-    h = model["num_attention_heads"]
-    hd = model.get("head_dim") or d // h
-    kv = model["num_key_value_heads"] * hd
-    ff = model["intermediate_size"]
-    return [("q", d, h * hd), ("k", d, kv), ("v", d, kv), ("o", h * hd, d),
-            ("gate", d, ff), ("up", d, ff), ("down", ff, d)]
-
-
 def projection_work(m: int, k: int, n: int, act_bytes: int
                     ) -> tuple[float, float]:
     """``(flops, bytes)`` of one ``[m, k] x [k, n]`` ternary projection."""
@@ -47,36 +37,5 @@ def projection_work(m: int, k: int, n: int, act_bytes: int
     return flops, nbytes
 
 
-def least_seconds(m: int, model: dict, act_bytes: int, peak_flops: float,
-                  hbm: float) -> float:
-    """Least time for every ternary projection of one forward over ``m``
-    rows: per projection the larger of its compute and its memory bound."""
-    per_layer = 0.0
-    for _, k, n in projections(model):
-        flops, nbytes = projection_work(m, k, n, act_bytes)
-        per_layer += max(flops / peak_flops, nbytes / hbm)
-    return per_layer * model["num_hidden_layers"]
-
-
-def ternary_params(model: dict) -> int:
-    return model["num_hidden_layers"] * sum(k * n for _, k, n in
-                                            projections(model))
-
-
-def attention_flops(model: dict, context: int) -> float:
-    """``QK^T`` and ``PV`` of one token over ``context`` positions."""
-    d = model["hidden_size"]
-    h = model["num_attention_heads"]
-    hd = model.get("head_dim") or d // h
-    return 4.0 * model["num_hidden_layers"] * h * hd * context
-
-
 def head_flops(model: dict) -> float:
     return 2.0 * model["hidden_size"] * model["vocab_size"]
-
-
-def token_flops(model: dict, context: int, head: bool) -> float:
-    """Model FLOPs of one token at ``context`` positions (itself included),
-    with the LM head where that token's logits are needed."""
-    return 2.0 * ternary_params(model) + attention_flops(model, context) + \
-        (head_flops(model) if head else 0.0)
